@@ -50,12 +50,16 @@ def run() -> list[dict]:
     env = dict(os.environ)
     env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
     env["PYTHONPATH"] = "src"
+    # the child compiles on 8 fake host devices and must never reach for
+    # the chip the parent process may already hold
+    env["JAX_PLATFORMS"] = "cpu"
     proc = subprocess.run([sys.executable, "-c", _CODE], env=env,
                           capture_output=True, text=True,
                           cwd=os.path.dirname(os.path.dirname(
                               os.path.abspath(__file__))), timeout=600)
     if proc.returncode != 0:
-        return [{"bench": "collective_overlap", "error": proc.stderr[-300:]}]
+        raise RuntimeError(f"collective_overlap child exited "
+                           f"{proc.returncode}: {proc.stderr[-2000:]}")
     import json
     data = json.loads(proc.stdout.strip().splitlines()[-1])
     rows = []

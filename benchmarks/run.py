@@ -19,7 +19,6 @@ from benchmarks import (
     multichannel_sweep,
     policy_ablation,
     qos_contention,
-    roofline,
     roshambo_table,
     sg_vs_pack,
     streaming_layers,
@@ -27,6 +26,7 @@ from benchmarks import (
     transfer_sweep,
     txrx_balance,
 )
+from repro.utils.compile_cache import enable_compile_cache
 
 BENCHES = {
     "transfer_sweep": transfer_sweep.run,  # Fig 4 / Fig 5
@@ -41,13 +41,12 @@ BENCHES = {
     "tenant_isolation": tenant_isolation.run,  # tier-2 heavy-hitter WFQ
     "fault_recovery": fault_recovery.run,  # quarantine + replan vs stall
     "collective_overlap": collective_overlap.run,  # blocks-mode collectives
-    "roofline": roofline.run,  # reads dry-run artifacts
 }
 
 
 def _derived(row: dict) -> str:
     for k in ("tx_us_per_byte", "roundtrip_ms", "frame_ms",
-              "dominant_term_s", "collective_bytes_per_dev", "tx_gbps",
+              "collective_bytes_per_dev", "tx_gbps",
               "token_rx_p99_ms"):
         if k in row:
             return f"{k}={row[k]}"
@@ -58,6 +57,7 @@ def main() -> None:
     ap = argparse.ArgumentParser()
     ap.add_argument("--only", default="")
     args = ap.parse_args()
+    enable_compile_cache()
     names = [n.strip() for n in args.only.split(",") if n.strip()] or list(
         BENCHES)
 

@@ -26,7 +26,7 @@ from repro.core.transfer import (
 BENCH_JSON = pathlib.Path(__file__).resolve().parent.parent / "BENCH_transfer.json"
 
 
-def _mlp_layers(n_layers: int, d: int, f: int, key):
+def mlp_layers(n_layers: int, d: int, f: int, key):
     """n_layers gated-MLP blocks as (name, host_params, apply)."""
     layers = []
 
@@ -70,7 +70,7 @@ def _variants():
 def run(n_layers: int = 8, d: int = 1024, f: int = 4096,
         repeats: int = 3) -> list[dict]:
     key = jax.random.PRNGKey(0)
-    layers = _mlp_layers(n_layers, d, f, key)
+    layers = mlp_layers(n_layers, d, f, key)
     x = np.asarray(jax.random.normal(key, (8, d)), np.float32)
     rows = []
     for name, path, policy, staged in _variants():

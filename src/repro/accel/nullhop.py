@@ -47,6 +47,8 @@ class NullHopExecutor:
         self.policy = policy
         self.staged = staged
         self.engine = TransferEngine(policy)
+        # one compiled step per layer, kept across frames
+        self._steps: dict = {}
 
     def close(self) -> None:
         self.engine.close()
@@ -54,15 +56,14 @@ class NullHopExecutor:
     def run_frame(self, params: dict, frame: np.ndarray) -> NullHopResult:
         """frame: [B, H, W, C]. Per-layer streamed execution + final FC."""
         cnn = self.cnn
-        jitted = {}
 
         def make_apply(spec):
             def apply_fn(dev_params, x):
                 w, b = dev_params
                 return cnn.layer_apply(spec, {"w": w, "b": b}, x)
-            if spec.name not in jitted:
-                jitted[spec.name] = jax.jit(apply_fn)
-            return jitted[spec.name]
+            if spec.name not in self._steps:
+                self._steps[spec.name] = jax.jit(apply_fn)
+            return self._steps[spec.name]
 
         layers = []
         for spec in cnn.cfg.layers:

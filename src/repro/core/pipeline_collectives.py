@@ -25,15 +25,12 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from repro.utils.jax_compat import axis_size as _axis_size  # noqa: F401
-from repro.utils.jax_compat import pvary
-
 
 def ring_all_gather(x: jax.Array, axis_name: str, *, axis: int = 0) -> jax.Array:
     """All-gather via an N-1 step ppermute ring (blocks mode).
 
     Equivalent to ``lax.all_gather(x, axis_name, axis=axis, tiled=True)``."""
-    n = _axis_size(axis_name)
+    n = lax.axis_size(axis_name)
     if n == 1:
         return x
     idx = lax.axis_index(axis_name)
@@ -57,7 +54,7 @@ def ring_reduce_scatter(x: jax.Array, axis_name: str, *, axis: int = 0) -> jax.A
 
     Equivalent to ``lax.psum_scatter(x, axis_name, scatter_dimension=axis,
     tiled=True)``."""
-    n = _axis_size(axis_name)
+    n = lax.axis_size(axis_name)
     if n == 1:
         return x
     idx = lax.axis_index(axis_name)
@@ -96,13 +93,13 @@ def overlapped_matmul_ag(
     Each ring step matmuls the chunk that just arrived while the next chunk
     is in flight — XLA schedules the ppermute DMA concurrently with the dot.
     Unique-mode reference: ``lax.all_gather(x, axis, tiled=True) @ w``."""
-    n = _axis_size(axis_name)
+    n = lax.axis_size(axis_name)
     if n == 1:
         return x @ w
     idx = lax.axis_index(axis_name)
     m_local = x.shape[0]
     out = jnp.zeros((n * m_local,) + (w.shape[-1],), _dot_dtype(x, w))
-    out = pvary(out, (axis_name,))  # mark carry as axis-varying for scan
+    out = lax.pvary(out, (axis_name,))  # mark carry as axis-varying for scan
     perm = [(i, (i + 1) % n) for i in range(n)]
 
     def step(carry, s):
@@ -130,7 +127,7 @@ def overlapped_matmul_rs(
     chunk j overlaps the dot producing chunk j+1. Result: rows m/n per rank,
     summed over the axis. Unique-mode reference:
     ``lax.psum_scatter(x @ w, axis, scatter_dimension=0, tiled=True)``."""
-    n = _axis_size(axis_name)
+    n = lax.axis_size(axis_name)
     if n == 1:
         return x @ w
     idx = lax.axis_index(axis_name)
@@ -151,7 +148,7 @@ def overlapped_matmul_rs(
         acc = acc + chunk_dot(c).astype(acc.dtype)
         return lax.ppermute(acc, axis_name, perm), None
 
-    acc = pvary(jnp.zeros((mc, w.shape[-1]), _dot_dtype(x, w)), (axis_name,))
+    acc = lax.pvary(jnp.zeros((mc, w.shape[-1]), _dot_dtype(x, w)), (axis_name,))
     acc, _ = lax.scan(step, acc, jnp.arange(n - 1))
     return (acc + chunk_dot(idx)).astype(_dot_dtype(x, w))
 

@@ -24,14 +24,14 @@ Staging layouts are resolved once per layer identity through the engine's
 zero pack *copies* when the host params are unchanged (inference weight
 streaming), the ZynqNet one-time-layout lesson.
 
-The seed's per-frame pack path (``np.concatenate`` per layer per frame,
+The seed's per-frame pack path (a fresh staging buffer per layer per frame,
 depth-2 max) is kept behind ``staged=False`` as the benchmark baseline.
 
 Two implementations:
 
-- :class:`HostStreamingExecutor` — real host->device staging (measured here);
-  used by the serving engine's ``layer_streaming`` mode and the NullHop
-  benchmarks.
+- :class:`HostStreamingExecutor` — real host->device staging; used by
+  :mod:`repro.accel.nullhop` and the streaming benchmarks (the serving
+  engines keep every weight on the device).
 - :func:`device_streamed_scan` — the on-device analogue for the dry-run: a
   ``jax.lax.scan`` over layers where each layer's params are all-gathered
   from their sharded resting place just-in-time (the TPU equivalent of
@@ -53,7 +53,6 @@ from repro.core.transfer import (
     StagedLayout,
     Ticket,
     TransferEngine,
-    _bitcast_from_bytes,
     reassemble_chunks,
 )
 
@@ -322,23 +321,28 @@ class HostStreamingExecutor:
             host_out = self.engine.rx([x_dev])[0]
             return host_out, timing
 
-        pending: Ticket | None = None
+        def tx_layer(params: list[np.ndarray]) -> tuple[StagedLayout, Ticket]:
+            # seed path: a fresh staging layout (allocation + copy) per frame
+            lay = StagedLayout(params)
+            return lay, self.engine.tx_async(lay.pack(params), layout=lay)
+
+        pending: tuple[StagedLayout, Ticket] | None = None
         if prefetch and layers:
-            pending = self.engine.tx_async(_pack(layers[0][1]))
+            pending = tx_layer(layers[0][1])
 
         host_out: np.ndarray | None = None
         for i, (name, params_host, apply_fn) in enumerate(layers):
             # --- TX params for this layer
             t0 = time.perf_counter()
             if prefetch:
-                chunks = pending.wait()
-                params_dev = _unpack(chunks, params_host)
+                lay, ticket = pending
+                params_dev = lay.unpack(ticket.wait())
                 # issue next layer's TX immediately (overlaps compute below)
                 if i + 1 < len(layers):
-                    pending = self.engine.tx_async(_pack(layers[i + 1][1]))
+                    pending = tx_layer(layers[i + 1][1])
             else:
-                chunks = self.engine.tx(_pack(params_host))
-                params_dev = _unpack(chunks, params_host)
+                lay = StagedLayout(params_host)
+                params_dev = lay.unpack(self.engine.tx(lay.pack(params_host)))
             tx_s = time.perf_counter() - t0
             tx_bytes = sum(np.asarray(p).nbytes for p in params_host)
             if i == 0:
@@ -362,29 +366,6 @@ class HostStreamingExecutor:
             )
             x_dev = y  # next layer consumes device-resident output
         return host_out, timing
-
-
-def _pack(arrays: list[np.ndarray]) -> np.ndarray:
-    """Seed-path pack: flatten a param list into one freshly-allocated
-    contiguous payload, every call. Superseded by
-    :meth:`repro.core.transfer.StagedLayout.pack`; kept as the measured
-    baseline."""
-    if not arrays:
-        return np.zeros((0,), np.float32)
-    return np.concatenate([np.asarray(a).reshape(-1).view(np.uint8) for a in arrays])
-
-
-def _unpack(chunks: list[jax.Array], ref: list[np.ndarray]) -> list[jax.Array]:
-    """Seed-path unpack: re-derives offsets from ``ref`` on every call (see
-    :meth:`StagedLayout.unpack` for the cached equivalent)."""
-    flat = reassemble_chunks(chunks)
-    out, off = [], 0
-    for a in ref:
-        a = np.asarray(a)
-        out.append(_bitcast_from_bytes(flat[off : off + a.nbytes], a.shape,
-                                       np.dtype(a.dtype)))
-        off += a.nbytes
-    return out
 
 
 def device_streamed_scan(

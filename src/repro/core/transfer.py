@@ -50,16 +50,17 @@ Partitioning: ``UNIQUE`` sends the payload in one transfer; ``BLOCKS`` splits
 it into ``block_bytes`` chunks (only BLOCKS lets a depth>=2 ring overlap
 within a single logical transfer).
 
-Everything here is *measured*, not simulated: on this container the device is
-CPU, but the staging/copy/dispatch structure (and therefore the relative
-behaviour the paper studies — fixed overhead vs per-byte cost, overlap gains)
-is real.
+Everything here is *measured*, not simulated: each transfer is a real
+``device_put``/``device_get`` to whatever device JAX holds. On a TPU that is
+the host->HBM link; on the CPU backend (where the tests run) it is a host
+memcpy, so CPU timings say nothing about the chip.
 """
 
 from __future__ import annotations
 
 import collections
 import enum
+import functools
 import math
 import threading
 import time
@@ -368,43 +369,75 @@ def reassemble_chunks(chunks: Sequence[jax.Array]) -> jax.Array:
     return jnp.concatenate([c.reshape(-1) for c in chunks])
 
 
-def _bitcast_from_bytes(seg: jax.Array, shape: tuple, dtype: np.dtype) -> jax.Array:
-    """Reinterpret a flat uint8 device segment as ``dtype`` with ``shape``."""
-    import jax.numpy as jnp
+def _word_dtype(dtypes: Sequence[np.dtype]) -> np.dtype:
+    """Unsigned word a staged payload ships as: the widest itemsize among
+    the layout's arrays, at most 4 bytes (the device runs without x64).
 
-    if dtype == np.uint8:
-        return seg.reshape(shape)
+    An array as wide as the word unpacks by a same-width bitcast; a
+    narrower one by a bitcast that splits each word. Bytes are never
+    reshaped into a trailing itemsize dimension, which a TPU pads to 128
+    lanes: compiled for a TPU v5e, a 32 MiB f32 weight unpacked that way
+    needed a 4 GiB temporary."""
+    width = min(max((d.itemsize for d in dtypes), default=1), 4)
+    return np.dtype(f"u{width}")
+
+
+def _from_words(seg: jax.Array, shape: tuple, dtype: np.dtype) -> jax.Array:
+    """Reinterpret a flat segment of unsigned words as ``dtype``/``shape``."""
     if dtype == np.bool_:
         # packed bools are 0/1 bytes; bitcast to bool isn't supported
-        return (seg != 0).reshape(shape)
-    if dtype.itemsize == 1:
-        return jax.lax.bitcast_convert_type(seg, dtype).reshape(shape)
-    return jax.lax.bitcast_convert_type(
-        seg.reshape(shape + (dtype.itemsize,)), jnp.dtype(dtype))
+        return _from_words(seg, shape, np.dtype(np.uint8)) != 0
+    if dtype.itemsize > seg.dtype.itemsize:
+        raise TypeError(f"cannot unpack {dtype} from {seg.dtype} words: the "
+                        f"device holds at most 4-byte elements")
+    vals = jax.lax.bitcast_convert_type(seg, dtype)  # [n] or [n, ratio]
+    return vals.reshape(-1)[:math.prod(shape)].reshape(shape)
+
+
+@functools.lru_cache(maxsize=256)
+def _unpack_program(specs: tuple, word: np.dtype) -> Callable:
+    """The compiled unpack of one layout: join the word chunks, cut every
+    array out of them. Layouts with equal specs share one program."""
+    w = word.itemsize
+
+    def unpack(chunks):
+        flat = reassemble_chunks(chunks)
+        return [_from_words(flat[off // w:(off + nb + w - 1) // w], shape, dt)
+                for off, shape, dt, nb in specs]
+    return jax.jit(unpack)
 
 
 class StagedLayout:
     """Precomputed pack/unpack plan for a fixed list of host arrays.
 
     Computes (offset, shape, dtype, nbytes) per array once and preallocates a
-    single pinned-style uint8 staging buffer. ``pack`` copies each array into
+    single pinned-style staging buffer. ``pack`` copies each array into
     its slot (skipping the copy entirely when the same array objects were
-    packed last time and ``force=False``); ``unpack`` slices/bitcasts device
-    chunks back into per-array device views using the cached offsets. Neither
-    allocates host memory after construction.
+    packed last time and ``force=False``); ``unpack`` runs one compiled
+    program that cuts the device chunks back into per-array device arrays
+    using the cached offsets. Neither allocates host memory after
+    construction.
+
+    The payload is a view of the staging buffer in unsigned words
+    (:func:`_word_dtype`), and every array starts on a word boundary, so
+    the device unpacks by bitcasts of whole words.
     """
 
-    __slots__ = ("specs", "nbytes", "_staging", "_payload", "_busy",
+    __slots__ = ("specs", "word", "nbytes", "_staging", "_payload", "_busy",
                  "_last_arrays", "pack_count", "copy_count", "_pool")
 
     def __init__(self, arrays: Sequence[np.ndarray], *,
                  pool: "Any | None" = None):
+        arrays = [np.asarray(a) for a in arrays]
+        self.word = _word_dtype([np.dtype(a.dtype) for a in arrays])
+        w = self.word.itemsize
         specs = []
         off = 0
         for a in arrays:
-            a = np.asarray(a)
+            off = -(-off // w) * w
             specs.append((off, a.shape, np.dtype(a.dtype), a.nbytes))
             off += a.nbytes
+        off = -(-off // w) * w
         self.specs: tuple = tuple(specs)
         self.nbytes = off
         # ``pool`` (e.g. repro.core.channels.StagingPool) recycles staging
@@ -415,7 +448,15 @@ class StagedLayout:
             self._staging = pool.acquire(max(off, 1))
         else:
             self._staging = np.empty(max(off, 1), np.uint8)
-        self._payload = self._staging[:off]  # stable view, identity-checkable
+        # the alignment gaps ship too: zero them once so a payload is a
+        # function of its arrays alone
+        end = 0
+        for a_off, _shape, _dtype, nb in specs:
+            self._staging[end:a_off] = 0
+            end = a_off + nb
+        self._staging[end:off] = 0
+        # stable view, identity-checkable
+        self._payload = self._staging[:off].view(self.word)
         self._busy: threading.Event | None = None  # set by engine on async tx
         # strong refs to the arrays staged last: identity comparison against
         # live objects is sound, whereas remembering bare id()s is not (a
@@ -474,13 +515,9 @@ class StagedLayout:
         return self._payload
 
     def unpack(self, chunks: Sequence[jax.Array]) -> list[jax.Array]:
-        """Slice device chunk(s) of a packed payload back into per-array
-        device views, using the cached offsets (no host round-trip)."""
-        flat = reassemble_chunks(chunks)
-        return [
-            _bitcast_from_bytes(flat[off:off + nb], shape, dtype)
-            for off, shape, dtype, nb in self.specs
-        ]
+        """Cut the device chunk(s) of a packed payload back into per-array
+        device arrays, using the cached offsets (no host round-trip)."""
+        return _unpack_program(self.specs, self.word)(list(chunks))
 
     def seg_sizes(self) -> list[int]:
         """Per-array byte sizes — the segment list the pack-vs-SG decision
@@ -1071,13 +1108,11 @@ class TransferEngine:
         host = np.asarray(jax.device_get(payload))
         if out is None:
             return host
-        # zero-copy RX: land the bytes in the CALLER's buffer; the only
-        # steady-state work is the one unavoidable device->host copy. On
-        # the CPU backend ``device_get`` returns a VIEW of the device
-        # buffer (verified: shares memory, tracemalloc-silent), so this is
-        # exactly one memcpy and zero allocations; on an accelerator
-        # backend device_get itself is the DMA and the copyto is the
-        # host-side landing (a dlpack/pinned-buffer path could fuse them).
+        # RX landing: the bytes end in the CALLER's buffer. Only on the CPU
+        # backend is this zero-copy: there ``device_get`` returns a view of
+        # the device buffer, so the landing is one memcpy and no
+        # allocation. On a TPU ``device_get`` is the DMA into a fresh host
+        # array and this copyto is a second, host-side copy.
         np.copyto(out.reshape(-1).view(np.uint8),
                   host.reshape(-1).view(np.uint8))
         return out

@@ -24,11 +24,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# jax 0.4.x renamed CompilerParams -> TPUCompilerParams; jax >= 0.5 renames
-# it back. Resolve whichever this jax provides.
-_CompilerParams = getattr(pltpu, "TPUCompilerParams", None) or pltpu.CompilerParams
-
-
 def _conv_kernel(x_ref, w_ref, b_ref, o_ref, *, kh: int, kw: int,
                  tile_h: int, out_w: int, relu: bool):
     # x: [1, 1, tile_h+kh-1, out_w+kw-1, Cin]; w: [kh, kw, Cin, Cout]
@@ -73,7 +68,7 @@ def conv2d_slabs(slabs: jax.Array, w: jax.Array, b: jax.Array, *,
         ],
         out_specs=pl.BlockSpec((1, 1, tile_h, out_w, cout),
                                lambda i, t: (i, t, 0, 0, 0)),
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel")),
         interpret=interpret,
     )(slabs.reshape(bsz, nt, slab_h, slab_w, cin), w, b)

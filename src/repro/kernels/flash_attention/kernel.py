@@ -25,10 +25,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# jax 0.4.x renamed CompilerParams -> TPUCompilerParams; jax >= 0.5 renames
-# it back. Resolve whichever this jax provides.
-_CompilerParams = getattr(pltpu, "TPUCompilerParams", None) or pltpu.CompilerParams
-
 NEG_INF = -2.0**30
 _INV_LN2 = 1.4426950408889634
 
@@ -127,7 +123,7 @@ def flash_attention_bhsd(q: jax.Array, k: jax.Array, v: jax.Array, *,
             pltpu.VMEM((bq,), jnp.float32),
             pltpu.VMEM((bq,), jnp.float32),
         ],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(q, k, v)

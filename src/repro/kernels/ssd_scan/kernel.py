@@ -1,18 +1,22 @@
 """SSD intra-chunk kernel (Mamba2 state-space duality).
 
-Computes, for each (batch, chunk) grid cell, the quadratic *intra-chunk*
-part of the SSD algorithm plus the chunk's boundary-state contribution:
+Computes, for each (batch, head, chunk) grid cell, the quadratic
+*intra-chunk* part of the SSD algorithm plus the chunk's boundary-state
+contribution:
 
-    y_diag[z]  = (C_z B_z^T * L_z) (x_z * dt_z)     [Q,H,P]
-    states[z]  = sum_k decay_out[k] B_k (x_k dt_k)  [H,P,N]
+    y_diag[z]  = (C_z B_z^T * L_z) (x_z * dt_z)     [Q,P]
+    states[z]  = sum_k decay_out[k] B_k (x_k dt_k)  [P,N]
 
 The sequential inter-chunk recurrence (O(n_chunks) tiny updates) stays in
 jnp — it is bandwidth-trivial. The chunk length Q is the BLOCKS knob: each
-grid step's VMEM working set is Q*(H*P + 2*G*N) + H*Q^2, and Pallas
-double-buffers consecutive chunks (the paper's overlap, again).
+grid step's VMEM working set is Q*(P + 2*N) + a few Q*Q f32 tiles, and
+Pallas double-buffers consecutive chunks (the paper's overlap, again).
 
-Grid: (B, n_chunks); heads stay inside the block (H*Q*Q f32 fits VMEM at
-the assigned configs: 48*256*256*4 = 12.6 MiB).
+Layout: the wrapper moves heads ahead of the sequence so every block ends
+in two dimensions the TPU can tile — (Q, P), (Q, N), (Q, 1), (1, Q) — and
+every in-kernel product is a plain 2-D matmul. The within-chunk cumulative
+sum is a product with a lower-triangular ones matrix, computed once as a
+column and once as a row.
 """
 
 from __future__ import annotations
@@ -24,51 +28,48 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# jax 0.4.x renamed CompilerParams -> TPUCompilerParams; jax >= 0.5 renames
-# it back. Resolve whichever this jax provides.
-_CompilerParams = getattr(pltpu, "TPUCompilerParams", None) or pltpu.CompilerParams
+_HI = jax.lax.Precision.HIGHEST
 
 
-def _ssd_chunk_kernel(x_ref, dt_ref, a_ref, b_ref, c_ref, y_ref, st_ref,
-                      dec_ref, *, q: int, h: int, p: int, g: int, n: int):
-    # refs (leading grid dims squeezed via index maps):
-    # x: [Q,H,P]  dt: [Q,H]  a: [H]  b,c: [Q,G,N]
-    x = x_ref[0, 0]
-    dt = dt_ref[0, 0].astype(jnp.float32)
-    a = a_ref[...].astype(jnp.float32)
-    b = b_ref[0, 0]
-    c = c_ref[0, 0]
-    rep = h // g
+def _ssd_chunk_kernel(x_ref, dtc_ref, dtr_ref, a_ref, b_ref, c_ref, y_ref,
+                      st_ref, dec_ref, *, q: int):
+    # blocks (leading grid dims are 1): x [Q,P]  dt as a column [Q,1] and a
+    # row [1,Q]  a [1,1]  b,c [Q,N]
+    x = x_ref[0, 0, 0]
+    dt_col = dtc_ref[0, 0, 0]
+    a = a_ref[0].astype(jnp.float32)
+    da_col = dt_col.astype(jnp.float32) * a  # [Q,1]
+    da_row = dtr_ref[0, 0, 0].astype(jnp.float32) * a  # [1,Q]
+    b = b_ref[0, 0, 0]
+    c = c_ref[0, 0, 0]
 
-    da = dt * a[None, :]  # [Q,H]
-    da_cs = jnp.cumsum(da, axis=0)  # [Q,H]
-
-    # L decay matrix per head: exp(segsum) lower-triangular
-    diff = da_cs[:, None, :] - da_cs[None, :, :]  # [Q,Q,H]
     qi = jax.lax.broadcasted_iota(jnp.int32, (q, q), 0)
     ki = jax.lax.broadcasted_iota(jnp.int32, (q, q), 1)
-    tri = (ki <= qi)[:, :, None]
-    l_mat = jnp.where(tri, jnp.exp(diff), 0.0)  # [Q,Q,H]
+    tri = ki <= qi  # [Q,Q] lower triangle incl. diagonal
+    ones_lo = tri.astype(jnp.float32)
+    cs_col = jnp.dot(ones_lo, da_col, precision=_HI,
+                     preferred_element_type=jnp.float32)  # cumsum, [Q,1]
+    cs_row = jax.lax.dot_general(
+        da_row, ones_lo, (((1,), (1,)), ((), ())), precision=_HI,
+        preferred_element_type=jnp.float32)  # the same cumsum, [1,Q]
+    cs_last = cs_row[:, q - 1:q]  # [1,1]
 
-    bh = jnp.repeat(b, rep, axis=1)  # [Q,H,N]
-    ch = jnp.repeat(c, rep, axis=1)
-    xdt = x * dt[..., None].astype(x.dtype)  # [Q,H,P]
+    # L decay matrix: exp(segsum) on the lower triangle
+    l_mat = jnp.where(tri, jnp.exp(cs_col - cs_row), 0.0)  # [Q,Q]
 
-    # cb[q,k,h] = sum_n c[q,h,n] b[k,h,n]
-    cb = jnp.einsum("qhn,khn->qkh", ch, bh,
-                    preferred_element_type=jnp.float32)
-    att = (cb * l_mat).astype(x.dtype)  # [Q,Q,H]
-    y_ref[0, 0] = jnp.einsum("qkh,khp->qhp", att, xdt,
-                          preferred_element_type=jnp.float32).astype(y_ref.dtype)
+    xdt = x * dt_col.astype(x.dtype)  # [Q,P]
+    cb = jax.lax.dot_general(c, b, (((1,), (1,)), ((), ())),
+                             preferred_element_type=jnp.float32)  # [Q,Q]
+    att = (cb * l_mat).astype(x.dtype)
+    y_ref[0, 0, 0] = jnp.dot(att, xdt, preferred_element_type=jnp.float32
+                             ).astype(y_ref.dtype)
 
-    # chunk state: sum_k exp(da_cs[-1] - da_cs[k]) b_k (x_k dt_k)
-    decay_states = jnp.exp(da_cs[-1][None, :] - da_cs).astype(x.dtype)  # [Q,H]
-    st_ref[0, 0] = jnp.einsum("khn,khp->hpn", bh * decay_states[..., None],
-                           xdt, preferred_element_type=jnp.float32
-                           ).astype(st_ref.dtype)
-    dec_ref[0, 0] = jnp.exp(da_cs[-1]).astype(dec_ref.dtype)  # [H]
-    # also emit decay-in per position for the off-diagonal jnp pass
-    # (folded into y by the caller: y += C_q . prev_state * exp(da_cs[q]))
+    # chunk state: sum_k exp(cs[-1] - cs[k]) b_k (x_k dt_k), as [P,N]
+    bd = b * jnp.exp(cs_last - cs_col).astype(b.dtype)  # [Q,N]
+    st_ref[0, 0, 0] = jax.lax.dot_general(
+        xdt, bd, (((0,), (0,)), ((), ())),
+        preferred_element_type=jnp.float32).astype(st_ref.dtype)
+    dec_ref[0, 0, 0] = jnp.exp(cs_last).astype(dec_ref.dtype)
 
 
 @functools.partial(jax.jit, static_argnames=("chunk", "interpret"))
@@ -83,34 +84,45 @@ def ssd_intra_chunk_call(x: jax.Array, dt: jax.Array, a: jax.Array,
     g, n = b.shape[2], b.shape[3]
     assert s % chunk == 0
     nc = s // chunk
-    grid = (bs, nc)
-    kernel = functools.partial(_ssd_chunk_kernel, q=chunk, h=h, p=p, g=g, n=n)
-    xc = x.reshape(bs, nc, chunk, h, p)
-    dtc = dt.reshape(bs, nc, chunk, h)
-    bc = b.reshape(bs, nc, chunk, g, n)
-    cc = c.reshape(bs, nc, chunk, g, n)
+    rep = h // g
+    kernel = functools.partial(_ssd_chunk_kernel, q=chunk)
+    # head-major: [B,H,nc,Q,...]; b/c stay per group (head -> h // rep)
+    xh = x.reshape(bs, nc, chunk, h, p).transpose(0, 3, 1, 2, 4)
+    dth = dt.reshape(bs, nc, chunk, h).transpose(0, 3, 1, 2)
+    bh = b.reshape(bs, nc, chunk, g, n).transpose(0, 3, 1, 2, 4)
+    ch = c.reshape(bs, nc, chunk, g, n).transpose(0, 3, 1, 2, 4)
+
+    def per_head(i, j, z):
+        return (i, j, z, 0, 0)
+
+    def per_group(i, j, z):
+        return (i, j // rep, z, 0, 0)
+
     y, st, dec = pl.pallas_call(
         kernel,
         out_shape=(
-            jax.ShapeDtypeStruct((bs, nc, chunk, h, p), jnp.float32),
-            jax.ShapeDtypeStruct((bs, nc, h, p, n), jnp.float32),
-            jax.ShapeDtypeStruct((bs, nc, h), jnp.float32),
+            jax.ShapeDtypeStruct((bs, h, nc, chunk, p), jnp.float32),
+            jax.ShapeDtypeStruct((bs, h, nc, p, n), jnp.float32),
+            jax.ShapeDtypeStruct((bs, h, nc, 1, 1), jnp.float32),
         ),
-        grid=grid,
+        grid=(bs, h, nc),
         in_specs=[
-            pl.BlockSpec((1, 1, chunk, h, p), lambda i, z: (i, z, 0, 0, 0)),
-            pl.BlockSpec((1, 1, chunk, h), lambda i, z: (i, z, 0, 0)),
-            pl.BlockSpec((h,), lambda i, z: (0,)),
-            pl.BlockSpec((1, 1, chunk, g, n), lambda i, z: (i, z, 0, 0, 0)),
-            pl.BlockSpec((1, 1, chunk, g, n), lambda i, z: (i, z, 0, 0, 0)),
+            pl.BlockSpec((1, 1, 1, chunk, p), per_head),
+            pl.BlockSpec((1, 1, 1, chunk, 1), per_head),
+            pl.BlockSpec((1, 1, 1, 1, chunk), per_head),
+            pl.BlockSpec((1, 1, 1), lambda i, j, z: (j, 0, 0)),
+            pl.BlockSpec((1, 1, 1, chunk, n), per_group),
+            pl.BlockSpec((1, 1, 1, chunk, n), per_group),
         ],
         out_specs=(
-            pl.BlockSpec((1, 1, chunk, h, p), lambda i, z: (i, z, 0, 0, 0)),
-            pl.BlockSpec((1, 1, h, p, n), lambda i, z: (i, z, 0, 0, 0)),
-            pl.BlockSpec((1, 1, h), lambda i, z: (i, z, 0)),
+            pl.BlockSpec((1, 1, 1, chunk, p), per_head),
+            pl.BlockSpec((1, 1, 1, p, n), per_head),
+            pl.BlockSpec((1, 1, 1, 1, 1), per_head),
         ),
-        compiler_params=_CompilerParams(
-            dimension_semantics=("parallel", "parallel")),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "parallel")),
         interpret=interpret,
-    )(xc, dtc, a, bc, cc)
-    return y.reshape(bs, s, h, p), st, dec
+    )(xh, dth[..., None], dth[..., None, :], a.reshape(h, 1, 1), bh, ch)
+    return (y.transpose(0, 2, 3, 1, 4).reshape(bs, s, h, p),
+            st.transpose(0, 2, 1, 3, 4),
+            dec.reshape(bs, h, nc).transpose(0, 2, 1))

@@ -26,10 +26,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# jax 0.4.x renamed CompilerParams -> TPUCompilerParams; jax >= 0.5 renames
-# it back. Resolve whichever this jax provides.
-_CompilerParams = getattr(pltpu, "TPUCompilerParams", None) or pltpu.CompilerParams
-
 
 def _matmul_kernel(x_ref, w_ref, o_ref, acc_ref, *, k_steps: int):
     @pl.when(pl.program_id(2) == 0)
@@ -68,7 +64,7 @@ def matmul_blocks(x: jax.Array, w: jax.Array, *, block_m: int = 512,
         ],
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, kk: (i, j)),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(x, w)

@@ -7,13 +7,24 @@
 from __future__ import annotations
 
 import argparse
+from typing import Any
 
 import jax
 import numpy as np
 
 from repro.configs.registry import ARCHS, get_config, smoke_config
-from repro.models.api import build_model
+from repro.models.api import Model, build_model
 from repro.serve.engine import ServeConfig, ServingEngine
+from repro.utils.compile_cache import enable_compile_cache
+
+
+def build(arch: str, *, smoke: bool, seed: int = 0) -> tuple[Model, Any]:
+    """The model and its random weights, made on the device from ``seed``
+    (``smoke`` selects the reduced same-family config). The init is one
+    compiled program, so no f32 temporary of a full-width weight is held."""
+    cfg = smoke_config(arch) if smoke else get_config(arch)
+    model = build_model(cfg)
+    return model, jax.jit(model.init)(jax.random.PRNGKey(seed))
 
 
 def main() -> None:
@@ -26,10 +37,10 @@ def main() -> None:
     ap.add_argument("--new-tokens", type=int, default=32)
     ap.add_argument("--temperature", type=float, default=0.0)
     args = ap.parse_args()
+    enable_compile_cache()
 
-    cfg = smoke_config(args.arch) if args.smoke else get_config(args.arch)
-    model = build_model(cfg)
-    params = model.init(jax.random.PRNGKey(0))
+    model, params = build(args.arch, smoke=args.smoke)
+    cfg = model.cfg
     max_seq = args.prompt_len + args.new_tokens + 8
     eng = ServingEngine(model, params,
                         ServeConfig(max_batch=args.batch, max_seq=max_seq,

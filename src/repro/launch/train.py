@@ -23,6 +23,7 @@ from repro.data.pipeline import DataConfig, StagedPipeline, SyntheticLMSource
 from repro.models.api import build_model
 from repro.optim import AdamWConfig
 from repro.train.loop import TrainConfig, Trainer
+from repro.utils.compile_cache import enable_compile_cache
 
 POLICIES = {
     "polling": TransferPolicy.user_level_polling,
@@ -47,6 +48,7 @@ def main() -> None:
     ap.add_argument("--checkpoint-dir", default="")
     ap.add_argument("--checkpoint-every", type=int, default=50)
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = smoke_config(args.arch) if args.smoke else get_config(args.arch)
     model = build_model(cfg)

@@ -17,24 +17,18 @@ def _seed_numpy():
     np.random.seed(0)
 
 
-# Test snippets are written against current-jax spellings (jax.shard_map,
-# AxisType, lax.pvary); install aliases when running on an older jax. Each
-# branch is a no-op on jax versions that already provide the API.
-_JAX_COMPAT_PREAMBLE = r"""
-import jax as _cjax
-if not hasattr(_cjax, "shard_map"):
-    from jax.experimental.shard_map import shard_map as _c_sm
-    _cjax.shard_map = _c_sm
-if not hasattr(_cjax.lax, "pvary"):
-    _cjax.lax.pvary = lambda _x, _names: _x
-if not hasattr(_cjax.sharding, "AxisType"):
-    class _CAxisType:
-        Auto = None
-    _cjax.sharding.AxisType = _CAxisType
-    _c_mm = _cjax.make_mesh
-    _cjax.make_mesh = (
-        lambda shape, names, axis_types=None, **kw: _c_mm(shape, names, **kw))
-"""
+@pytest.fixture
+def own_runtime():
+    """A process-default TransferRuntime for this test alone. Admission
+    sheds a backlogged tenant when the class's recent deadline-miss rate
+    is high; on a busy host, earlier tests' token traffic in the shared
+    runtime's 5 s window would otherwise shed this test's requests."""
+    from repro.core.runtime import TransferRuntime, set_runtime
+    rt = TransferRuntime()
+    prev = set_runtime(rt)
+    yield rt
+    set_runtime(prev)
+    rt.close()
 
 
 def run_in_subprocess(code: str, n_devices: int = 8) -> str:
@@ -46,7 +40,6 @@ def run_in_subprocess(code: str, n_devices: int = 8) -> str:
     env = dict(os.environ)
     env["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={n_devices}"
     env["PYTHONPATH"] = "src"
-    code = _JAX_COMPAT_PREAMBLE + code
     out = subprocess.run([sys.executable, "-c", code], capture_output=True,
                          text=True, env=env, cwd=os.path.dirname(
                              os.path.dirname(os.path.abspath(__file__))),

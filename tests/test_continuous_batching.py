@@ -3,10 +3,13 @@ single-request generation."""
 
 import jax
 import numpy as np
+import pytest
 
 from repro.configs.registry import smoke_config
 from repro.models.api import build_model
 from repro.serve.continuous import ContinuousBatchingEngine, Request
+
+pytestmark = pytest.mark.usefixtures("own_runtime")
 
 
 def _setup():

@@ -31,18 +31,9 @@ def test_trip_count_multiplication(n):
 def test_matches_xla_on_unrolled():
     c = jax.jit(_scan_matmul(6, unroll=6)).lower(X, W).compile()
     xla = c.cost_analysis()
-    if isinstance(xla, (list, tuple)):  # jax 0.4.x returns [dict]
-        xla = xla[0]
     mine = analyze(c.as_text())
     assert mine.flops == pytest.approx(float(xla["flops"]), rel=1e-6)
-    if jax.__version_info__ >= (0, 5):
-        # jax 0.4.x HLO contains unfused scan-boundary copies that XLA's own
-        # "bytes accessed" excludes; the byte comparison only holds on the
-        # cleaner HLO newer versions emit.
-        assert mine.bytes == pytest.approx(float(xla["bytes accessed"]),
-                                           rel=0.05)
-    else:
-        assert mine.bytes >= float(xla["bytes accessed"])
+    assert mine.bytes == pytest.approx(float(xla["bytes accessed"]), rel=0.05)
 
 
 def test_nested_scans_multiply():

@@ -264,7 +264,7 @@ def test_admission_sheds_on_deadline_miss_rate():
         pending.wait()
 
 
-def test_continuous_batching_submit_returns_decision():
+def test_continuous_batching_submit_returns_decision(own_runtime):
     from repro.configs.registry import smoke_config
     from repro.models.api import build_model
     from repro.serve.continuous import ContinuousBatchingEngine, Request
