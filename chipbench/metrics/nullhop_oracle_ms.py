@@ -1,0 +1,9 @@
+"""Mean per frame of the program's repro.nullhop.oracle span over the
+traced window's frames: the eager sparsity pass, one host sync a layer."""
+
+from chipbench.harness import program_spans
+
+
+def read(run):
+    w = program_spans.window(run)
+    return w.per_frame_ms("repro.nullhop.oracle") if w else None

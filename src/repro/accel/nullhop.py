@@ -23,6 +23,7 @@ import numpy as np
 from repro.accel.roshambo import RoShamBoCNN
 from repro.core.streaming import FrameTiming, HostStreamingExecutor
 from repro.core.transfer import TransferEngine, TransferPolicy
+from repro.utils.spans import span
 
 
 @dataclass
@@ -54,7 +55,18 @@ class NullHopExecutor:
         self.engine.close()
 
     def run_frame(self, params: dict, frame: np.ndarray) -> NullHopResult:
-        """frame: [B, H, W, C]. Per-layer streamed execution + final FC."""
+        """frame: [B, H, W, C]. Per-layer streamed execution + final FC.
+
+        Spans: ``repro.nullhop.frame`` (the whole call; it starts the
+        frame every nested and worker-side span shares), and inside it
+        ``repro.nullhop.stream`` (the streamed layers),
+        ``repro.nullhop.oracle`` (the sparsity pass, one
+        ``repro.nullhop.oracle.layer`` per layer including its host sync)
+        and ``repro.nullhop.fc`` (the host head)."""
+        with span("repro.nullhop.frame", new_frame=True):
+            return self._run_frame(params, frame)
+
+    def _run_frame(self, params: dict, frame: np.ndarray) -> NullHopResult:
         cnn = self.cnn
 
         def make_apply(spec):
@@ -72,15 +84,20 @@ class NullHopExecutor:
                            make_apply(spec)))
 
         executor = HostStreamingExecutor(self.engine, staged=self.staged)
-        out_host, timing = executor.run(layers, np.asarray(frame))
+        with span("repro.nullhop.stream"):
+            out_host, timing = executor.run(layers, np.asarray(frame))
 
         sparsity = []  # recompute per-layer zero fractions (oracle pass)
-        x = jnp.asarray(frame)
-        for spec in cnn.cfg.layers:
-            x = cnn.layer_apply(spec, params[spec.name], x)
-            sparsity.append(float((x == 0).mean()))
+        with span("repro.nullhop.oracle"):
+            x = jnp.asarray(frame)
+            for spec in cnn.cfg.layers:
+                with span("repro.nullhop.oracle.layer"):
+                    x = cnn.layer_apply(spec, params[spec.name], x)
+                    sparsity.append(float((x == 0).mean()))
 
         # classifier head runs on the PS in the paper (host-side)
-        feats = out_host.reshape(out_host.shape[0], -1)
-        logits = feats @ np.asarray(params["fc"]["w"]) + np.asarray(params["fc"]["b"])
+        with span("repro.nullhop.fc"):
+            feats = out_host.reshape(out_host.shape[0], -1)
+            logits = (feats @ np.asarray(params["fc"]["w"])
+                      + np.asarray(params["fc"]["b"]))
         return NullHopResult(logits, timing, sparsity, self.policy.tag)

@@ -188,6 +188,7 @@ from dataclasses import dataclass, field
 from typing import Any, Callable
 
 from repro.analysis.validated import assert_held, make_condition, make_lock
+from repro.utils import spans
 
 # Per-class rolling window of dispatch/service latencies (bytes/counters are
 # exact lifetime totals; latency percentiles come from this recent window).
@@ -269,6 +270,9 @@ DEFAULT_COALESCE: dict[PriorityClass, CoalescePolicy] = {
 # latency-critical descriptors that must never sit behind an in-service
 # bulk chunk on every worker at once.
 _LATENCY_CLASSES = (PriorityClass.TOKEN, PriorityClass.SENSOR)
+# per class: submit to first dispatch, and each service stint on a worker
+_QUEUE_SPAN = {c: f"repro.runtime.queue.{c.value}" for c in PriorityClass}
+_SERVICE_SPAN = {c: f"repro.runtime.service.{c.value}" for c in PriorityClass}
 # Classes whose descriptors may be submitted as PreemptibleWork (throughput
 # traffic that yields to the latency classes mid-chunk).
 PREEMPTIBLE_CLASSES = (PriorityClass.LAYER, PriorityClass.BULK)
@@ -746,7 +750,8 @@ class _Descriptor:
     __slots__ = ("fn", "done", "out", "cls", "nbytes", "handle",
                  "t_submit", "deadline", "on_cancel",
                  "started", "service_acc", "t_parked", "preemptions",
-                 "units", "tenant", "weight")
+                 "units", "tenant", "weight", "origin", "t_submit_ns",
+                 "t_dispatch_ns")
 
     def __init__(self, fn: Callable[[], Any], cls: PriorityClass,
                  nbytes: int, handle: "RuntimeHandle", deadline_s: float,
@@ -766,7 +771,14 @@ class _Descriptor:
         self.tenant = tenant
         self.weight = max(float(weight), 1e-9)
         self.handle = handle
+        # the submitter's spans.current(): queue and service spans recorded
+        # on a worker name the span that submitted them, and its frame
+        self.origin = spans.current()
         self.t_submit = time.monotonic()
+        self.t_submit_ns = time.perf_counter_ns()
+        # first dispatch, stamped under the runtime lock; the queue span is
+        # filed outside it, when the worker starts the descriptor
+        self.t_dispatch_ns = 0
         self.deadline = self.t_submit + deadline_s
         # invoked (outside the runtime lock) iff the descriptor is cancelled
         # while still queued: the submitter's own completion protocol (ring
@@ -1191,6 +1203,7 @@ class TransferRuntime:
         """Choose the next descriptor. Caller holds ``_cond``."""
         assert_held(self._cond, "_pick_locked")
         now = time.monotonic()
+        now_ns = time.perf_counter_ns()
         self._cap_wait_hint = None
         if not self.fair:
             # FIFO baseline: oldest submit across every class (and across
@@ -1293,6 +1306,7 @@ class TransferRuntime:
                 self._queues[d.cls].charge_dispatch(d)
             st.dispatched += 1
             st.dispatch_lat_s.append(now - d.t_submit)
+            d.t_dispatch_ns = now_ns
             ts = st.tenant(d.tenant)
             ts.dispatched += 1
             ts.dispatch_lat_s.append(now - d.t_submit)
@@ -1394,7 +1408,14 @@ class TransferRuntime:
             elif stay:
                 continue
 
-    def _park_locked_check(self, d: _Descriptor, t_stint: float) -> bool:
+    @staticmethod
+    def _record_service(d: _Descriptor, t0_ns: int, t1_ns: int) -> None:
+        """One service stint of ``d`` (its whole service unless parked):
+        the stamps its ``service_acc`` adds up."""
+        spans.record(_SERVICE_SPAN[d.cls], t0_ns, t1_ns, frame=d.origin[0],
+                     parent=d.origin[1], nbytes=d.nbytes)
+
+    def _park_locked_check(self, d: _Descriptor, t_stint: int) -> bool:
         """Between two segments of a PreemptibleWork: park ``d`` iff a
         latency-class descriptor is waiting and no idle worker can take it.
         Returns True when parked (the caller must NOT complete the
@@ -1409,7 +1430,8 @@ class TransferRuntime:
                 return False
             if not any(self._queues[c] for c in _LATENCY_CLASSES):
                 return False
-            d.service_acc += time.perf_counter() - t_stint
+            t_end = time.perf_counter_ns()
+            d.service_acc += (t_end - t_stint) * 1e-9
             d.preemptions += 1
             d.t_parked = time.monotonic()
             # renewed deadline: EDF must see the park as a fresh arrival,
@@ -1422,7 +1444,8 @@ class TransferRuntime:
             self._executing -= 1
             self._executing_by[d.cls] -= 1
             self._cond.notify()
-            return True
+        self._record_service(d, t_stint, t_end)
+        return True
 
     def _execute(self, d: _Descriptor) -> bool:
         """Run a descriptor body (possibly one stint of a PreemptibleWork).
@@ -1430,7 +1453,12 @@ class TransferRuntime:
         work = d.fn if isinstance(d.fn, PreemptibleWork) else None
         result: Any = None
         err: BaseException | None = None
-        t0 = time.perf_counter()
+        if d.t_dispatch_ns:
+            spans.record(_QUEUE_SPAN[d.cls], d.t_submit_ns, d.t_dispatch_ns,
+                         frame=d.origin[0], parent=d.origin[1],
+                         nbytes=d.nbytes)
+            d.t_dispatch_ns = 0
+        t0 = time.perf_counter_ns()
         if work is None:
             try:
                 result = d.fn()
@@ -1447,7 +1475,9 @@ class TransferRuntime:
                     break
                 if not work.exhausted and self._park_locked_check(d, t0):
                     return False
-        d.service_acc += time.perf_counter() - t0
+        t1 = time.perf_counter_ns()
+        d.service_acc += (t1 - t0) * 1e-9
+        self._record_service(d, t0, t1)
         if work is not None and work.finalize is not None:
             try:
                 work.finalize(err)

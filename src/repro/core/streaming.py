@@ -41,7 +41,6 @@ Two implementations:
 
 from __future__ import annotations
 
-import time
 from dataclasses import dataclass, field
 from typing import Any, Callable, Sequence
 
@@ -55,6 +54,7 @@ from repro.core.transfer import (
     TransferEngine,
     reassemble_chunks,
 )
+from repro.utils.spans import span
 
 
 @dataclass
@@ -112,6 +112,15 @@ class HostStreamingExecutor:
     ``staged=False`` selects the legacy per-frame pack path (re-concatenates
     params every frame) — kept only as the measured baseline for
     ``BENCH_transfer.json``.
+
+    Spans (:mod:`repro.utils.spans`): ``repro.stream.input_tx``, and per
+    layer ``repro.stream.tx_wait`` (the wait for the layer's parameters —
+    the whole transfer where it is synchronous — and their unpack),
+    ``repro.stream.pack`` (staging pack and submit, ``nbytes`` = layout
+    bytes), ``repro.stream.compute`` (the layer program to completion) and
+    ``repro.stream.rx_wait``. :class:`LayerTiming` is computed from their
+    stamps: ``tx_s`` is the wait plus the packs issued right after it, and
+    layer 0's also holds the input TX.
 
     ``sensor_fn``: optional frame-ingest callable, registered as a
     ``SENSOR``-class background task for the duration of each ``run()`` —
@@ -209,11 +218,11 @@ class HostStreamingExecutor:
 
     # -- shared input staging ----------------------------------------------
     def _tx_input(self, x: np.ndarray) -> tuple[jax.Array, float, int]:
-        t0 = time.perf_counter()
         xa = np.asarray(x)
-        dev_chunks = self.engine.tx(xa)
-        x_dev = reassemble_chunks(dev_chunks).reshape(xa.shape)
-        return x_dev, time.perf_counter() - t0, xa.nbytes
+        with span("repro.stream.input_tx", xa.nbytes) as s:
+            dev_chunks = self.engine.tx(xa)
+            x_dev = reassemble_chunks(dev_chunks).reshape(xa.shape)
+        return x_dev, s.ns * 1e-9, xa.nbytes
 
     # -- new path: cached layouts + three-way overlap -----------------------
     def _run_overlapped(self, layers, x) -> tuple[np.ndarray, FrameTiming]:
@@ -245,20 +254,26 @@ class HostStreamingExecutor:
                       and hasattr(engine, "prefer_sg")
                       and policy.management is Management.INTERRUPT)
 
-        def issue_tx() -> None:
+        def issue_tx() -> int:
+            """Refill the TX window; returns the nanoseconds its packs and
+            submits took."""
             nonlocal next_tx
+            pack_ns = 0
             while next_tx < len(layers) and len(pending_tx) < tx_window:
                 name, params, _ = layers[next_tx]
                 lay = layouts[next_tx]
-                if sg_capable and engine.layouts.decide_sg(
-                        (next_tx, name), lay, engine.prefer_sg):
-                    pending_tx.append(
-                        ("sg", engine.tx_sg(lay.sg_segments(params))))
-                else:
-                    payload = lay.pack(params)
-                    pending_tx.append(
-                        ("pack", engine.tx_async(payload, layout=lay)))
+                with span("repro.stream.pack", lay.nbytes) as s:
+                    if sg_capable and engine.layouts.decide_sg(
+                            (next_tx, name), lay, engine.prefer_sg):
+                        pending_tx.append(
+                            ("sg", engine.tx_sg(lay.sg_segments(params))))
+                    else:
+                        payload = lay.pack(params)
+                        pending_tx.append(
+                            ("pack", engine.tx_async(payload, layout=lay)))
+                pack_ns += s.ns
                 next_tx += 1
+            return pack_ns
 
         issue_tx()
 
@@ -270,38 +285,37 @@ class HostStreamingExecutor:
             if pending_rx is None:
                 return
             j, ticket = pending_rx
-            t0 = time.perf_counter()
-            host_out = ticket.wait()[0]
-            timing.layers[j].rx_s += time.perf_counter() - t0
+            with span("repro.stream.rx_wait") as s:
+                host_out = ticket.wait()[0]
+            timing.layers[j].rx_s += s.ns * 1e-9
             pending_rx = None
 
         for i, (name, params_host, apply_fn) in enumerate(layers):
             # --- TX: wait for this layer's in-flight params, then refill the
-            # ring window (layers i+1 .. i+depth-1 stream during compute)
-            t0 = time.perf_counter()
-            kind, ticket = pending_tx.pop(0)
-            if kind == "sg":
-                # SG segments are whole arrays: results arrive shaped, no
-                # staging unpack (and no staging buffer was ever touched).
-                params_dev = ticket.wait()
-            else:
-                params_dev = layouts[i].unpack(ticket.wait())
-            issue_tx()
-            tx_s = time.perf_counter() - t0
+            # ring window (layers i+1 .. i+depth-1 stream during compute);
+            # the refill's packs count as this layer's TX time
+            with span("repro.stream.tx_wait") as s:
+                kind, ticket = pending_tx.pop(0)
+                if kind == "sg":
+                    # SG segments are whole arrays: results arrive shaped,
+                    # no staging unpack (and no staging buffer was touched).
+                    params_dev = ticket.wait()
+                else:
+                    params_dev = layouts[i].unpack(ticket.wait())
+            tx_s = (s.ns + issue_tx()) * 1e-9
             tx_bytes = layouts[i].nbytes
             if i == 0:
                 tx_s += input_tx_s
                 tx_bytes += input_bytes
 
             # --- compute (layer k-1's RX and layer k+1's TX are in flight)
-            t0 = time.perf_counter()
-            y = apply_fn(params_dev, x_dev)
-            y.block_until_ready()
-            compute_s = time.perf_counter() - t0
+            with span("repro.stream.compute") as s:
+                y = apply_fn(params_dev, x_dev)
+                y.block_until_ready()
 
             rx_bytes = int(y.size) * y.dtype.itemsize
             timing.layers.append(
-                LayerTiming(name, tx_s, compute_s, 0.0, tx_bytes, rx_bytes)
+                LayerTiming(name, tx_s, s.ns * 1e-9, 0.0, tx_bytes, rx_bytes)
             )
             # --- RX: retire layer k-1's ticket, launch layer k's — an
             # interior fmap streams back into its reused host buffer; the
@@ -321,49 +335,59 @@ class HostStreamingExecutor:
             host_out = self.engine.rx([x_dev])[0]
             return host_out, timing
 
-        def tx_layer(params: list[np.ndarray]) -> tuple[StagedLayout, Ticket]:
+        def tx_layer(params: list[np.ndarray]
+                     ) -> tuple[StagedLayout, Ticket, int]:
             # seed path: a fresh staging layout (allocation + copy) per frame
-            lay = StagedLayout(params)
-            return lay, self.engine.tx_async(lay.pack(params), layout=lay)
+            with span("repro.stream.pack") as s:
+                lay = StagedLayout(params)
+                s.nbytes = lay.nbytes
+                ticket = self.engine.tx_async(lay.pack(params), layout=lay)
+            return lay, ticket, s.ns
 
-        pending: tuple[StagedLayout, Ticket] | None = None
+        pending: tuple[StagedLayout, Ticket, int] | None = None
         if prefetch and layers:
             pending = tx_layer(layers[0][1])
 
         host_out: np.ndarray | None = None
         for i, (name, params_host, apply_fn) in enumerate(layers):
-            # --- TX params for this layer
-            t0 = time.perf_counter()
+            # --- TX params for this layer (with prefetch, the next layer's
+            # pack and submit count as this layer's TX time)
             if prefetch:
-                lay, ticket = pending
-                params_dev = lay.unpack(ticket.wait())
+                lay, ticket, _ = pending
+                with span("repro.stream.tx_wait") as s:
+                    params_dev = lay.unpack(ticket.wait())
+                tx_ns = s.ns
                 # issue next layer's TX immediately (overlaps compute below)
                 if i + 1 < len(layers):
                     pending = tx_layer(layers[i + 1][1])
+                    tx_ns += pending[2]
             else:
-                lay = StagedLayout(params_host)
-                params_dev = lay.unpack(self.engine.tx(lay.pack(params_host)))
-            tx_s = time.perf_counter() - t0
+                with span("repro.stream.pack") as pk:
+                    lay = StagedLayout(params_host)
+                    pk.nbytes = lay.nbytes
+                    payload = lay.pack(params_host)
+                with span("repro.stream.tx_wait") as s:
+                    params_dev = lay.unpack(self.engine.tx(payload))
+                tx_ns = pk.ns + s.ns
+            tx_s = tx_ns * 1e-9
             tx_bytes = sum(np.asarray(p).nbytes for p in params_host)
             if i == 0:
                 tx_s += input_tx_s
                 tx_bytes += input_bytes
 
             # --- compute
-            t0 = time.perf_counter()
-            y = apply_fn(params_dev, x_dev)
-            y.block_until_ready()
-            compute_s = time.perf_counter() - t0
+            with span("repro.stream.compute") as s:
+                y = apply_fn(params_dev, x_dev)
+                y.block_until_ready()
+            compute_s = s.ns * 1e-9
 
             # --- RX (per the paper, each layer's output returns to the PS)
-            t0 = time.perf_counter()
-            host_out = self.engine.rx(
-                [y], out=self._rx_out(i, y, last=i == len(layers) - 1))[0]
-            rx_s = time.perf_counter() - t0
+            with span("repro.stream.rx_wait") as s:
+                host_out = self.engine.rx(
+                    [y], out=self._rx_out(i, y, last=i == len(layers) - 1))[0]
 
-            timing.layers.append(
-                LayerTiming(name, tx_s, compute_s, rx_s, tx_bytes, host_out.nbytes)
-            )
+            timing.layers.append(LayerTiming(
+                name, tx_s, compute_s, s.ns * 1e-9, tx_bytes, host_out.nbytes))
             x_dev = y  # next layer consumes device-resident output
         return host_out, timing
 

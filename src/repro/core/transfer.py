@@ -85,6 +85,7 @@ from repro.core.runtime import (
     get_runtime,
 )
 from repro.core.qos import QosSpec, resolve_submit_qos
+from repro.utils import spans
 
 __all__ = [  # re-exports: the fault taxonomy lives in runtime (no cycle)
     "Management", "Buffering", "Partitioning", "TransferPolicy",
@@ -111,6 +112,8 @@ _SG_SAMPLE_WINDOW = 64
 # dodging the staging memcpy cannot lose to per-segment overhead).
 _SG_FALLBACK_MAX_SEGMENTS = 16
 _SG_FALLBACK_MIN_SEG_BYTES = 1 << 18
+# one span per recorded transfer, first chunk start to last chunk done
+_XFER_SPAN = {"tx": "repro.xfer.tx", "rx": "repro.xfer.rx"}
 
 
 class Management(enum.Enum):
@@ -244,20 +247,6 @@ class TransferStats:
     direction: str  # "tx" (host->device) or "rx" (device->host)
     policy_tag: str
     management: str = ""  # Management mode the transfer ran under
-
-    @property
-    def us_per_byte(self) -> float:
-        return (self.wall_s * 1e6) / max(self.nbytes, 1)
-
-    @property
-    def gbps(self) -> float:
-        return self.nbytes / max(self.wall_s, 1e-12) / 1e9
-
-    def row(self) -> str:
-        return (
-            f"{self.policy_tag},{self.direction},{self.nbytes},"
-            f"{self.wall_s * 1e3:.4f},{self.us_per_byte:.6f},{self.n_chunks}"
-        )
 
 
 def _payload_nbytes(payload: Any, direction: str) -> int:
@@ -1000,8 +989,10 @@ class TransferEngine:
             release = threading.Event()
             self._buffers_busy[idx] = release
             self._buf_idx += 1
-        if prev is not None:
-            prev.wait()  # kernel driver: safe, waits for completion
+        if prev is not None and not prev.is_set():
+            # kernel driver: safe, waits for completion
+            with spans.span("repro.xfer.slot_wait"):
+                prev.wait()
         with self._ring_lock:
             if self._slot_held[idx]:
                 self.slot_collisions += 1
@@ -1025,7 +1016,14 @@ class TransferEngine:
         with self._stats_lock:
             self._observers.append(fn)
 
-    def _record(self, stats: TransferStats) -> None:
+    def _record(self, stats: TransferStats, t0_ns: int, t1_ns: int,
+                origin: tuple[int | None, int | None]) -> None:
+        """Count ``stats`` and file the transfer, from the
+        ``perf_counter_ns`` stamps its ``wall_s`` came from, as a
+        ``repro.xfer.tx`` / ``repro.xfer.rx`` span on behalf of ``origin``
+        (the submitter's ``spans.current()``)."""
+        spans.record(_XFER_SPAN[stats.direction], t0_ns, t1_ns,
+                     frame=origin[0], parent=origin[1], nbytes=stats.nbytes)
         if not stats.management:
             stats.management = self.policy.management.value
         with self._stats_lock:
@@ -1049,14 +1047,15 @@ class TransferEngine:
         (``priority=`` is the deprecated spelling of ``qos.priority``)."""
         spec = self._resolve_qos("tx", qos, priority)
         chunks = _split(np.asarray(host_array), self.policy)
-        t0 = time.perf_counter()
+        t0 = time.perf_counter_ns()
         out = self._run_chunks(
             [(c, "tx", None) for c in chunks], spec,
         )
-        wall = time.perf_counter() - t0
+        t1 = time.perf_counter_ns()
         self._record(
-            TransferStats(host_array.nbytes, wall, len(chunks), "tx", self.policy.tag)
-        )
+            TransferStats(host_array.nbytes, (t1 - t0) * 1e-9, len(chunks),
+                          "tx", self.policy.tag),
+            t0, t1, spans.current())
         return out
 
     # -- RX: device -> host -------------------------------------------------
@@ -1074,13 +1073,14 @@ class TransferEngine:
         arrays = list(device_arrays)
         outs = _check_out(arrays, out)
         nbytes = sum(int(a.size) * a.dtype.itemsize for a in arrays)
-        t0 = time.perf_counter()
+        t0 = time.perf_counter_ns()
         result = self._run_chunks(
             [(a, "rx", o) for a, o in zip(arrays, outs)], spec)
-        wall = time.perf_counter() - t0
+        t1 = time.perf_counter_ns()
         self._record(
-            TransferStats(nbytes, wall, len(arrays), "rx", self.policy.tag)
-        )
+            TransferStats(nbytes, (t1 - t0) * 1e-9, len(arrays), "rx",
+                          self.policy.tag),
+            t0, t1, spans.current())
         return result
 
     def _preempt_segments_for(self, payload, direction: str,
@@ -1291,6 +1291,7 @@ class TransferEngine:
         slot only this runtime can release — self-deadlock); hand follow-up
         transfers to another thread via the ticket instead."""
         handle = self._runtime_handle()
+        origin = spans.current()
         master = threading.Event()
         ticket_out: list = []
         results: list = [None] * len(payloads)
@@ -1330,11 +1331,11 @@ class TransferEngine:
             if first_err is not None:
                 ticket_out.append(first_err)
             else:
-                wall = time.perf_counter() - (state["t0"]
-                                              or time.perf_counter())
+                t1 = time.perf_counter_ns()
+                t0 = state["t0"] or t1
                 self._record(TransferStats(
-                    nbytes, wall, len(payloads), direction,
-                    self.policy.tag))
+                    nbytes, (t1 - t0) * 1e-9, len(payloads), direction,
+                    self.policy.tag), t0, t1, origin)
                 # preemptible chunks landed per-segment lists: splice them
                 # back into one flat, ordered chunk list for the caller.
                 flat_results = _flatten_chunk_results(results)
@@ -1368,7 +1369,7 @@ class TransferEngine:
                     return None
                 with state_lock:
                     if state["t0"] is None:
-                        state["t0"] = time.perf_counter()
+                        state["t0"] = time.perf_counter_ns()
                 try:
                     results[i] = self._one_timed(p, direction, o)
                 except BaseException as e:
@@ -1406,7 +1407,7 @@ class TransferEngine:
                                 "transfer failed")
                         with state_lock:
                             if state["t0"] is None:
-                                state["t0"] = time.perf_counter()
+                                state["t0"] = time.perf_counter_ns()
                         return self._one_timed(s, direction)
                     return run
 
@@ -1507,16 +1508,19 @@ class TransferEngine:
             return tickets
         total = sum(sizes)
         mode = self.policy.management.value
+        origin = spans.current()
 
-        def resolve(errs: list, results: list, wall: float) -> None:
+        def resolve(errs: list, results: list, t0: int = 0,
+                    t1: int = 0) -> None:
             # single completion handoff for the whole group: one recorded
             # TransferStats (successful bytes/descriptors only — exact
             # accounting), then every ticket resolves in submission order.
             ok_bytes = sum(sz for sz, e in zip(sizes, errs) if e is None)
             ok_n = sum(1 for e in errs if e is None)
             if ok_n:
+                wall = (t1 - t0) * 1e-9
                 self._record(TransferStats(ok_bytes, wall, ok_n, direction,
-                                           self.policy.tag))
+                                           self.policy.tag), t0, t1, origin)
                 if ok_n > 1 and wall > 0.0:
                     # grouped-transaction sample: the SG/batched crossover
                     # refits the per-segment walk cost from (k, total, wall)
@@ -1533,7 +1537,7 @@ class TransferEngine:
         def work():
             results: list = [None] * n
             errs: list[BaseException | None] = [None] * n
-            t0 = time.perf_counter()
+            t0 = time.perf_counter_ns()
             try:
                 fused = (n > 1 and not self.policy.checksum
                          and type(self)._one is TransferEngine._one)
@@ -1577,7 +1581,7 @@ class TransferEngine:
                             errs[i] = e
             finally:
                 self._release_buffer(idx, release)
-                resolve(errs, results, time.perf_counter() - t0)
+                resolve(errs, results, t0, time.perf_counter_ns())
 
         def cancelled(err: BaseException) -> None:
             # the group descriptor was cancelled while queued: ``work``
@@ -1586,7 +1590,7 @@ class TransferEngine:
             with self._stats_lock:
                 self.chunks_cancelled += n
             self._release_buffer(idx, release)
-            resolve([err] * n, [None] * n, 0.0)
+            resolve([err] * n, [None] * n)
 
         try:
             handle.submit(work, nbytes=total, qos=qos,
@@ -1596,7 +1600,7 @@ class TransferEngine:
             # every ticket (uniform with the async API — errors surface at
             # wait(), never from the submit call).
             self._release_buffer(idx, release)
-            resolve([e] * n, [None] * n, 0.0)
+            resolve([e] * n, [None] * n)
         return tickets
 
     def tx_many(self, host_arrays: Sequence[np.ndarray],
