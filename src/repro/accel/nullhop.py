@@ -9,7 +9,9 @@ modes and the buffering/partitioning knobs all apply.
 Also models NullHop's sparsity awareness: the accelerator skips zero
 activations (sparse feature-map encoding); we report the measured activation
 sparsity per layer (ReLU output) alongside timings, since it determines the
-effective RX payload on the real device.
+effective RX payload on the real device. It is counted on the host over the
+output fmaps the stream already returned (the per-layer RX), so it costs no
+second pass over the network and no device work.
 """
 
 from __future__ import annotations
@@ -17,7 +19,6 @@ from __future__ import annotations
 from dataclasses import dataclass
 
 import jax
-import jax.numpy as jnp
 import numpy as np
 
 from repro.accel.roshambo import RoShamBoCNN
@@ -60,9 +61,14 @@ class NullHopExecutor:
         Spans: ``repro.nullhop.frame`` (the whole call; it starts the
         frame every nested and worker-side span shares), and inside it
         ``repro.nullhop.stream`` (the streamed layers),
-        ``repro.nullhop.oracle`` (the sparsity pass, one
-        ``repro.nullhop.oracle.layer`` per layer including its host sync)
-        and ``repro.nullhop.fc`` (the host head)."""
+        ``repro.nullhop.oracle`` (the sparsity count, one
+        ``repro.nullhop.oracle.layer`` per layer, ``nbytes`` = the fmap it
+        counted) and ``repro.nullhop.fc`` (the host head).
+
+        ``sparsity`` is each layer's zero fraction, counted on the host
+        over the output fmap its RX returned
+        (:attr:`HostStreamingExecutor.last_outputs`): no jax op, transfer
+        or sync. NaN counts as non-zero, as in ``(y == 0).mean()``."""
         with span("repro.nullhop.frame", new_frame=True):
             return self._run_frame(params, frame)
 
@@ -87,13 +93,11 @@ class NullHopExecutor:
         with span("repro.nullhop.stream"):
             out_host, timing = executor.run(layers, np.asarray(frame))
 
-        sparsity = []  # recompute per-layer zero fractions (oracle pass)
+        sparsity = []
         with span("repro.nullhop.oracle"):
-            x = jnp.asarray(frame)
-            for spec in cnn.cfg.layers:
-                with span("repro.nullhop.oracle.layer"):
-                    x = cnn.layer_apply(spec, params[spec.name], x)
-                    sparsity.append(float((x == 0).mean()))
+            for y in executor.last_outputs:
+                with span("repro.nullhop.oracle.layer", y.nbytes):
+                    sparsity.append(1.0 - np.count_nonzero(y) / y.size)
 
         # classifier head runs on the PS in the paper (host-side)
         with span("repro.nullhop.fc"):

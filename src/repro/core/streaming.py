@@ -129,6 +129,9 @@ class HostStreamingExecutor:
     completion dispatches; under SCHEDULED the cooperative scheduler
     interleaves it between DMA chunks; under POLLING it starves (the
     paper's warning: the polling driver blocks the whole system).
+
+    :attr:`last_outputs` keeps the last ``run()``'s per-layer host fmaps;
+    :mod:`repro.accel.nullhop` reads its per-layer sparsity from them.
     """
 
     def __init__(self, engine: "TransferEngine | Any", *, staged: bool = True,
@@ -147,6 +150,16 @@ class HostStreamingExecutor:
         # aliasing each other.
         self.zero_copy_rx = zero_copy_rx
         self._rx_bufs: dict[Any, np.ndarray] = {}
+        self._outputs: list[np.ndarray] = []
+
+    @property
+    def last_outputs(self) -> tuple[np.ndarray, ...]:
+        """The last ``run()``'s host fmaps, one per layer, in layer order:
+        references to what RX delivered, not copies. Interior entries may
+        be the reused ``zero_copy_rx`` buffers, so they are valid until the
+        next ``run()`` of this executor; the last entry is the array that
+        ``run()`` returned."""
+        return tuple(self._outputs)
 
     def _rx_out(self, key: Any, y: jax.Array, *,
                 last: bool) -> list[np.ndarray] | None:
@@ -205,6 +218,7 @@ class HostStreamingExecutor:
         overlapped = (
             policy.management is Management.INTERRUPT and policy.depth >= 2
         )
+        self._outputs = []
         unregister_sensor = self._register_sensor()
         try:
             if overlapped and self.staged:
@@ -287,6 +301,7 @@ class HostStreamingExecutor:
             j, ticket = pending_rx
             with span("repro.stream.rx_wait") as s:
                 host_out = ticket.wait()[0]
+            self._outputs.append(host_out)
             timing.layers[j].rx_s += s.ns * 1e-9
             pending_rx = None
 
@@ -385,6 +400,7 @@ class HostStreamingExecutor:
             with span("repro.stream.rx_wait") as s:
                 host_out = self.engine.rx(
                     [y], out=self._rx_out(i, y, last=i == len(layers) - 1))[0]
+            self._outputs.append(host_out)
 
             timing.layers.append(LayerTiming(
                 name, tx_s, compute_s, s.ns * 1e-9, tx_bytes, host_out.nbytes))

@@ -208,4 +208,8 @@ def test_frame_spans_are_the_layer_timing(policy):
             and r.thread == root.thread]
     assert sorted(r.name for r in kids) == [
         "repro.nullhop.fc", "repro.nullhop.oracle", "repro.nullhop.stream"]
-    assert len(_by_name(recs, "repro.nullhop.oracle.layer")) == n
+    # the sparsity count ran on every layer's RX'd fmap
+    counted = _by_name(recs, "repro.nullhop.oracle.layer")
+    assert len(counted) == n
+    assert sum(r.nbytes for r in counted) == sum(
+        r.nbytes for r in _by_name(recs, "repro.xfer.rx"))

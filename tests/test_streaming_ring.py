@@ -5,6 +5,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from repro.core.channels import ChannelGroup
 from repro.core.streaming import HostStreamingExecutor
 from repro.core.transfer import (
     Buffering,
@@ -31,11 +32,16 @@ def _layers(n, d, key):
     return out
 
 
-def _reference(layers, x):
-    y = jnp.asarray(x)
+def _per_layer(layers, x):
+    y, out = jnp.asarray(x), []
     for _, (w, b), fn in layers:
         y = fn([jnp.asarray(w), jnp.asarray(b)], y)
-    return np.asarray(y)
+        out.append(np.asarray(y))
+    return out
+
+
+def _reference(layers, x):
+    return _per_layer(layers, x)[-1]
 
 
 @pytest.mark.parametrize("policy", [
@@ -123,3 +129,35 @@ def test_empty_layer_list_returns_transferred_input(staged):
     np.testing.assert_array_equal(np.asarray(out).reshape(x.shape), x)
     assert timing.layers == []
     eng.close()
+
+
+@pytest.mark.parametrize("zero_copy_rx", [True, False], ids=["zc", "copy"])
+@pytest.mark.parametrize("make_engine,staged", [
+    (lambda: TransferEngine(TransferPolicy.kernel_level_ring(4)), True),
+    (lambda: TransferEngine(TransferPolicy.kernel_level_ring(4)), False),
+    (lambda: TransferEngine(TransferPolicy.user_level_polling()), True),
+    (lambda: ChannelGroup(TransferPolicy.kernel_level_ring(4), n_channels=2,
+                          min_stripe_bytes=1 << 10), True),
+], ids=["overlapped", "basic-prefetch", "basic-polling", "group"])
+def test_last_outputs_are_each_layers_rx(make_engine, staged, zero_copy_rx):
+    """One host fmap per layer, in layer order, each that layer's device
+    output; the last is the array run() returned, and a second run's
+    entries hold the second frame."""
+    layers = _layers(4, 48, jax.random.PRNGKey(5))
+    eng = make_engine()
+    ex = HostStreamingExecutor(eng, staged=staged, zero_copy_rx=zero_copy_rx)
+    assert ex.last_outputs == ()
+    try:
+        for seed in (0, 1):
+            x = np.random.default_rng(seed).random((2, 48), np.float32)
+            out, _ = ex.run(layers, x)
+            got = ex.last_outputs
+            assert len(got) == len(layers)
+            assert got[-1] is out
+            for g, want in zip(got, _per_layer(layers, x)):
+                np.testing.assert_array_equal(np.asarray(g).reshape(
+                    want.shape), want)
+        ex.run([], x)
+        assert ex.last_outputs == ()
+    finally:
+        eng.close()

@@ -7,7 +7,7 @@ import numpy as np
 import pytest
 
 from repro.accel.nullhop import NullHopExecutor
-from repro.accel.roshambo import RoShamBoCNN
+from repro.accel.roshambo import RoShamBoCNN, RoShamBoConfig
 from repro.configs.registry import smoke_config
 from repro.core.transfer import (
     Buffering,
@@ -140,6 +140,35 @@ def test_nullhop_streamed_equals_monolithic():
         assert len(res.timing.layers) == 5
         assert res.timing.frame_s > 0
         assert all(0.0 <= s <= 1.0 for s in res.sparsity)
+
+
+@pytest.mark.parametrize("policy,staged", [
+    (TransferPolicy.kernel_level_ring(4), True),
+    (TransferPolicy.user_level_polling(), True),
+    (TransferPolicy(Management.INTERRUPT, Buffering.DOUBLE,
+                    Partitioning.BLOCKS, block_bytes=1 << 12), True),
+    (TransferPolicy.kernel_level_ring(4), False),
+], ids=["ring4", "polling", "interrupt-blocks", "ring4-unstaged"])
+def test_nullhop_sparsity_equals_eager_recompute(policy, staged):
+    """The per-layer sparsity counted over the RX'd fmaps is the zero
+    fraction an independent eager pass over the network reads."""
+    cnn = RoShamBoCNN(RoShamBoConfig(input_hw=32))
+    params = jax.tree.map(np.asarray, cnn.init(jax.random.PRNGKey(2)))
+    frames = np.random.default_rng(2).standard_normal(
+        (2, 1, 32, 32, 1)).astype(np.float32)
+    ex = NullHopExecutor(cnn, policy, staged=staged)
+    try:
+        for frame in frames:
+            res = ex.run_frame(params, frame)
+            x, ref = jnp.asarray(frame), []
+            for spec in cnn.cfg.layers:
+                x = cnn.layer_apply(spec, params[spec.name], x)
+                ref.append(float((x == 0).mean()))
+            assert res.sparsity == ref
+            assert all(type(v) is float for v in res.sparsity)
+            assert any(v > 0 for v in res.sparsity)
+    finally:
+        ex.close()
 
 
 def test_streaming_executor_streams_params_per_layer():
