@@ -1,6 +1,6 @@
 """Mean per frame of run_frame's wall time less the streamed layers'
-FrameTiming.frame_s: the oracle sparsity pass, the host FC head and the
-executor's bookkeeping."""
+FrameTiming.frame_s: the sparsity count over the returned fmaps, the
+host FC head and the executor's bookkeeping."""
 
 import numpy as np
 

@@ -1,5 +1,6 @@
 """Mean per frame of the program's repro.nullhop.oracle span over the
-traced window's frames: the eager sparsity pass, one host sync a layer."""
+traced window's frames: the per-layer sparsity, a zero count on the host
+over each layer's fmap that the stream already returned."""
 
 from chipbench.harness import program_spans
 

@@ -115,3 +115,35 @@ def test_new_cells_come_from_new_files_only(tmp_path):
     assert "served_tokens" in {m.name for m in cell.per_layer}
     assert b.cell("tiny-cnn.tinyring").traffic["management"] == \
         "kernel_level_ring"
+
+
+def test_tiny_cells_join_serving_metrics_the_benchmark_holds(tmp_path):
+    """Once BENCHMARK.json holds the serving metrics for an LM cell, the
+    tiny serving cell joins their entries instead of shadowing them."""
+    from chipbench.tests.conftest import SERVING_METRICS
+
+    root = copy_checkout(tmp_path / "checkout")
+    doc = json.loads((root / "BENCHMARK.json").read_text())
+    doc["configs"].append({
+        "name": "h2o-danube-1.8b", "source": "test", "reduced": [],
+        "why": "test", "file": "chipbench/configs/h2o-danube-1.8b.json"})
+    doc["workloads"].append({
+        "name": "h2o-danube-1.8b.chat", "config": "h2o-danube-1.8b",
+        "traffic": "chat", "chips": 1, "why": "test"})
+    for kind, m in SERVING_METRICS:
+        doc[kind].append(dict(m, workloads=["h2o-danube-1.8b.chat"]))
+    (root / "BENCHMARK.json").write_text(json.dumps(doc))
+    add_tiny_cells(root)
+    b = Benchmark(root)
+    assert b.problems() == []
+    names = [m["name"] for m in b.doc["end_to_end"] + b.doc["per_layer"]]
+    assert len(names) == len(set(names))
+    serving = {m["name"] for _kind, m in SERVING_METRICS} | {"setup_s"}
+    for cell in ("h2o-danube-1.8b.chat", "tiny-lm.tinychat"):
+        c = b.cell(cell)
+        assert {m.name for m in c.end_to_end + c.per_layer} == serving
+    for cell in ("roshambo.ring4", "roshambo.polling"):
+        c = b.cell(cell)
+        assert {m.name for m in c.end_to_end} == {
+            "setup_s", "frames_per_s", "frame_p95_ms"}
+        assert not {m.name for m in c.per_layer} & serving
