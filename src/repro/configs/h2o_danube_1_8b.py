@@ -12,6 +12,7 @@ def config() -> ModelConfig:
         n_layers=24, d_model=2560, vocab=32000,
         n_heads=32, n_kv_heads=8, d_ff=6912, sliding_window=4096,
         mlp="gated_silu", norm="rms", rope_theta=10_000.0,
+        rms_norm_eps=1e-5,
     )
 
 

@@ -18,9 +18,14 @@ _ARCH_MODULES = {
     "mamba2-780m": "repro.configs.mamba2_780m",
     "zamba2-1.2b": "repro.configs.zamba2_1_2b",
     "roshambo-nullhop": "repro.configs.roshambo",
+    "moonlight-16b-a3b": "repro.configs.moonlight_16b_a3b",
 }
 
-ARCHS = tuple(k for k in _ARCH_MODULES if k != "roshambo-nullhop")
+# the dry-run / training architectures; roshambo runs through the NullHop
+# executor, and moonlight is served with its experts in host memory
+# (repro.models.moonlight), neither through build_model
+ARCHS = tuple(k for k in _ARCH_MODULES
+              if k not in ("roshambo-nullhop", "moonlight-16b-a3b"))
 
 
 def get_config(name: str, **overrides) -> ModelConfig:

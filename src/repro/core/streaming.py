@@ -30,8 +30,9 @@ depth-2 max) is kept behind ``staged=False`` as the benchmark baseline.
 Two implementations:
 
 - :class:`HostStreamingExecutor` — real host->device staging; used by
-  :mod:`repro.accel.nullhop` and the streaming benchmarks (the serving
-  engines keep every weight on the device).
+  :mod:`repro.accel.nullhop`, the streaming benchmarks and, for a MoE
+  model's routed experts kept in host memory, :mod:`repro.models.moonlight`
+  (the other serving models keep every weight on the device).
 - :func:`device_streamed_scan` — the on-device analogue for the dry-run: a
   ``jax.lax.scan`` over layers where each layer's params are all-gathered
   from their sharded resting place just-in-time (the TPU equivalent of
@@ -41,6 +42,7 @@ Two implementations:
 
 from __future__ import annotations
 
+import collections
 from dataclasses import dataclass, field
 from typing import Any, Callable, Sequence
 
@@ -132,6 +134,10 @@ class HostStreamingExecutor:
 
     :attr:`last_outputs` keeps the last ``run()``'s per-layer host fmaps;
     :mod:`repro.accel.nullhop` reads its per-layer sparsity from them.
+
+    :meth:`stream_routed` streams parameter groups that are chosen only
+    once a layer has started (a MoE layer's routed experts, after its
+    router ran) and hands each to the caller as it lands.
     """
 
     def __init__(self, engine: "TransferEngine | Any", *, staged: bool = True,
@@ -229,6 +235,48 @@ class HostStreamingExecutor:
             unregister_sensor()
         self._frame_end()
         return out
+
+    # -- groups chosen at run time (routed experts) ------------------------
+    def stream_routed(
+        self,
+        groups: Sequence[Sequence[np.ndarray]],
+        apply_fn: Callable[[int, list[jax.Array]], None],
+    ) -> None:
+        """Move each group of host arrays to the device and call
+        ``apply_fn(j, device_arrays)`` for group j, in order, as it lands.
+
+        A group goes as one ``tx_sg`` transaction, one segment per array,
+        straight from the host arrays (no staging copy). Up to
+        ``depth - 1`` groups are in flight while the host hands landed ones
+        to ``apply_fn``, which dispatches device work without waiting for
+        it: group j+1's TX overlaps group j's compute. INTERRUPT management
+        only.
+
+        Spans: ``repro.stream.expert_tx`` per group's submit (``nbytes`` =
+        the group's bytes; its transfer's ``repro.xfer.tx`` record is its
+        child) and ``repro.stream.expert_wait`` per group, the wait for it
+        to land."""
+        engine = self.engine
+        if engine.policy.management is not Management.INTERRUPT:
+            raise ValueError("stream_routed needs INTERRUPT management")
+        sizes = [sum(int(a.nbytes) for a in g) for g in groups]
+        window = max(1, engine.policy.depth - 1)
+        pending: collections.deque = collections.deque()
+        issued = 0
+
+        def issue() -> None:
+            nonlocal issued
+            while issued < len(groups) and len(pending) < window:
+                with span("repro.stream.expert_tx", sizes[issued]):
+                    pending.append(engine.tx_sg(list(groups[issued])))
+                issued += 1
+
+        issue()
+        for j in range(len(groups)):
+            with span("repro.stream.expert_wait"):
+                dev = pending.popleft().wait()
+            issue()
+            apply_fn(j, dev)
 
     # -- shared input staging ----------------------------------------------
     def _tx_input(self, x: np.ndarray) -> tuple[jax.Array, float, int]:

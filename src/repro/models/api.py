@@ -51,10 +51,17 @@ class Model:
     prefill: Callable[..., Any]
     decode: Callable[..., Any]
     init_cache: Callable[..., Any]
+    # prefill/decode drive their own programs from the host, layer by layer
+    # (repro.models.moonlight): callers run them as they are, not under jit
+    host_loop: bool = False
 
 
 def build_model(cfg: ModelConfig) -> Model:
     fam = cfg.family
+    if fam == "mla_moe":
+        raise NotImplementedError(
+            f"{cfg.name}: served with its experts in host memory; build it "
+            f"with repro.models.moonlight.ExpertOffload(...).model()")
 
     # ---- forward ----
     if fam == "audio":

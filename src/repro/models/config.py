@@ -7,6 +7,9 @@ Families:
 - ``moe``    : mixture-of-experts FFN (deepseek-moe, granite-moe)
 - ``ssm``    : attention-free Mamba2 / SSD (mamba2-780m)
 - ``hybrid`` : Mamba2 backbone + shared attention blocks (zamba2)
+- ``mla_moe``: latent attention + leading dense layers + sigmoid-routed
+  fine-grained MoE (moonlight, the DeepSeek-V3 block); served with its
+  routed experts in host memory by :mod:`repro.models.moonlight`
 """
 
 from __future__ import annotations
@@ -38,6 +41,7 @@ class ModelConfig:
     d_ff: int = 0
     mlp: str = "gated_silu"  # gated_silu | gelu
     norm: str = "rms"  # rms | ln
+    rms_norm_eps: float = 1e-6
     tie_embeddings: bool = False
     # -- moe --
     n_experts: int = 0
@@ -52,6 +56,14 @@ class ModelConfig:
     # +78%% collective). Default off; knob kept for future meshes.
     moe_ep_sharding: bool = False
     router_aux_coef: float = 0.01
+    # routed weights' scale (mla_moe's sigmoid router, moe.sigmoid_route)
+    routed_scaling: float = 1.0
+    first_dense_layers: int = 0  # leading dense layers, MLP width d_ff
+    # -- multi-head latent attention (family mla_moe; q_lora_rank null) --
+    kv_lora_rank: int = 0
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
     # -- ssm (mamba2 / SSD) --
     ssm_state: int = 0  # N
     ssm_expand: int = 2
@@ -147,6 +159,8 @@ class ModelConfig:
         attn = D * H * Dh + 2 * D * Hkv * Dh + H * Dh * D
         if self.qkv_bias:
             attn += (H + 2 * Hkv) * Dh
+        if self.family == "mla_moe":
+            return self._mla_moe_params()
         if self.family == "moe":
             E, Fe, S = self.n_experts, self.d_expert or F, self.n_shared_experts
             ff = E * (3 * D * Fe) + S * (3 * D * Fe) + D * E
@@ -167,6 +181,20 @@ class ModelConfig:
             lora = n_app * 2 * (2 * D * self.hybrid_lora_rank)
             return emb + L * ssm_per + shared + lora + D
         return total
+
+    def _mla_moe_params(self) -> int:
+        D, H, R = self.d_model, self.n_heads, self.kv_lora_rank
+        dn, dr, dv = (self.qk_nope_head_dim, self.qk_rope_head_dim,
+                      self.v_head_dim)
+        attn = (D * H * (dn + dr) + D * (R + dr) + R + R * H * (dn + dv)
+                + H * dv * D)
+        E, Fe, S = self.n_experts, self.d_expert, self.n_shared_experts
+        moe = (E + S) * 3 * D * Fe + D * E + E  # experts, router, bias
+        n_dense = self.first_dense_layers
+        per = attn + 2 * D
+        return (2 * self.vocab_padded * D + D + self.n_layers * per
+                + n_dense * 3 * D * self.d_ff
+                + (self.n_layers - n_dense) * moe)
 
     def _ssm_params(self) -> int:
         D, Din, N, G, H = (self.d_model, self.d_inner, self.ssm_state,

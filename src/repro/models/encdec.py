@@ -71,37 +71,37 @@ def encode(cfg: ModelConfig, params: dict, frames: jax.Array) -> jax.Array:
     """frames: [B, S_enc, D] (stub frontend output) -> encoder states."""
 
     def body(x, p):
-        h, _ = attn_apply(p["attn"], apply_norm(cfg.norm, p["ln1"], x),
+        h, _ = attn_apply(p["attn"], apply_norm(cfg.norm, p["ln1"], x, cfg.rms_norm_eps),
                           n_heads=cfg.n_heads, n_kv=cfg.n_kv_heads,
                           head_dim=cfg.head_dim_, rope_theta=cfg.rope_theta,
                           kv_chunk=cfg.attn_kv_chunk,
                           blocks_threshold=cfg.attn_blocks_threshold,
                           causal=False)
         x = x + h
-        x = x + mlp_apply(p["mlp"], apply_norm(cfg.norm, p["ln2"], x), cfg.mlp)
+        x = x + mlp_apply(p["mlp"], apply_norm(cfg.norm, p["ln2"], x, cfg.rms_norm_eps), cfg.mlp)
         return x, None
 
     fn = make_remat(cfg)(body)
     x, _ = jax.lax.scan(fn, frames.astype(_dt(cfg)), params["enc_blocks"])
-    return apply_norm(cfg.norm, params["enc_norm"], x)
+    return apply_norm(cfg.norm, params["enc_norm"], x, cfg.rms_norm_eps)
 
 
 def _dec_block(cfg, p, x, enc, self_cache=None, cross_cache=None):
-    h, new_self = attn_apply(p["attn"], apply_norm(cfg.norm, p["ln1"], x),
+    h, new_self = attn_apply(p["attn"], apply_norm(cfg.norm, p["ln1"], x, cfg.rms_norm_eps),
                              n_heads=cfg.n_heads, n_kv=cfg.n_kv_heads,
                              head_dim=cfg.head_dim_, rope_theta=cfg.rope_theta,
                              kv_chunk=cfg.attn_kv_chunk,
                              blocks_threshold=cfg.attn_blocks_threshold,
                              cache=self_cache)
     x = x + h
-    h, new_cross = attn_apply(p["xattn"], apply_norm(cfg.norm, p["ln_x"], x),
+    h, new_cross = attn_apply(p["xattn"], apply_norm(cfg.norm, p["ln_x"], x, cfg.rms_norm_eps),
                               n_heads=cfg.n_heads, n_kv=cfg.n_kv_heads,
                               head_dim=cfg.head_dim_, rope_theta=0.0,
                               kv_chunk=cfg.attn_kv_chunk,
                               blocks_threshold=cfg.attn_blocks_threshold,
                               xk=enc, cache=cross_cache, causal=False)
     x = x + h
-    x = x + mlp_apply(p["mlp"], apply_norm(cfg.norm, p["ln2"], x), cfg.mlp)
+    x = x + mlp_apply(p["mlp"], apply_norm(cfg.norm, p["ln2"], x, cfg.rms_norm_eps), cfg.mlp)
     return x, new_self, new_cross
 
 
@@ -117,7 +117,7 @@ def forward(cfg: ModelConfig, params: dict, frames: jax.Array,
 
     fn = make_remat(cfg)(body)
     x, _ = jax.lax.scan(fn, x, params["dec_blocks"])
-    x = apply_norm(cfg.norm, params["final_norm"], x)
+    x = apply_norm(cfg.norm, params["final_norm"], x, cfg.rms_norm_eps)
     logits = jnp.einsum("bsd,dv->bsv", x, params["lm_head"],
                         preferred_element_type=jnp.float32)
     return logits, jnp.zeros((), jnp.float32)
@@ -158,7 +158,7 @@ def prefill(cfg: ModelConfig, params: dict, frames: jax.Array,
     fn = make_remat(cfg)(body)
     x, (new_self, new_cross) = jax.lax.scan(
         fn, x, (params["dec_blocks"], caches["self"], caches["cross"]))
-    x = apply_norm(cfg.norm, params["final_norm"], x[:, -1:])
+    x = apply_norm(cfg.norm, params["final_norm"], x[:, -1:], cfg.rms_norm_eps)
     logits = jnp.einsum("bsd,dv->bsv", x, params["lm_head"],
                         preferred_element_type=jnp.float32)
     return logits, {"self": new_self, "cross": new_cross}
@@ -185,14 +185,14 @@ def decode_step(cfg: ModelConfig, params: dict, token: jax.Array, caches):
 
     x, new_self = jax.lax.scan(body, x, (params["dec_blocks"], caches["self"],
                                          caches["cross"]))
-    x = apply_norm(cfg.norm, params["final_norm"], x)
+    x = apply_norm(cfg.norm, params["final_norm"], x, cfg.rms_norm_eps)
     logits = jnp.einsum("bsd,dv->bsv", x, params["lm_head"],
                         preferred_element_type=jnp.float32)
     return logits, {"self": new_self, "cross": caches["cross"]}
 
 
 def _dec_block_cached(cfg, p, x, self_cache: KVCache, cross_cache: KVCache):
-    h, new_self = attn_apply(p["attn"], apply_norm(cfg.norm, p["ln1"], x),
+    h, new_self = attn_apply(p["attn"], apply_norm(cfg.norm, p["ln1"], x, cfg.rms_norm_eps),
                              n_heads=cfg.n_heads, n_kv=cfg.n_kv_heads,
                              head_dim=cfg.head_dim_, rope_theta=cfg.rope_theta,
                              kv_chunk=cfg.attn_kv_chunk,
@@ -202,12 +202,12 @@ def _dec_block_cached(cfg, p, x, self_cache: KVCache, cross_cache: KVCache):
     # cross-attention straight against the cached projected encoder K/V
     from repro.models.layers.attention import attention
     b, s, _ = x.shape
-    xq = apply_norm(cfg.norm, p["ln_x"], x)
+    xq = apply_norm(cfg.norm, p["ln_x"], x, cfg.rms_norm_eps)
     q = (xq @ p["xattn"]["wq"] + p["xattn"].get("bq", 0)).reshape(
         b, s, cfg.n_heads, cfg.head_dim_)
     o = attention(q, cross_cache.k, cross_cache.v, causal=False,
                   kv_valid=cross_cache.length, kv_chunk=cfg.attn_kv_chunk,
                   blocks_threshold=cfg.attn_blocks_threshold)
     x = x + o.reshape(b, s, cfg.n_heads * cfg.head_dim_) @ p["xattn"]["wo"]
-    x = x + mlp_apply(p["mlp"], apply_norm(cfg.norm, p["ln2"], x), cfg.mlp)
+    x = x + mlp_apply(p["mlp"], apply_norm(cfg.norm, p["ln2"], x, cfg.rms_norm_eps), cfg.mlp)
     return x, new_self, cross_cache

@@ -100,7 +100,7 @@ def _shared_block(cfg: ModelConfig, shared: dict, loras: dict, gi: int,
     hd = _head_dim2(cfg)
     b, s, _ = x.shape
     h = jnp.concatenate([x, x0], axis=-1)
-    hn = apply_norm(cfg.norm, shared["ln1"], h)
+    hn = apply_norm(cfg.norm, shared["ln1"], h, cfg.rms_norm_eps)
 
     p = shared["attn"]
     q = hn @ p["wq"] + (hn @ loras["a_q"][gi]) @ loras["b_q"][gi]  # LoRA on q
@@ -126,7 +126,7 @@ def _shared_block(cfg: ModelConfig, shared: dict, loras: dict, gi: int,
                       blocks_threshold=cfg.attn_blocks_threshold)
     h = h + o.reshape(b, s, cfg.n_heads * hd) @ p["wo"]
 
-    h2 = apply_norm(cfg.norm, shared["ln2"], h)
+    h2 = apply_norm(cfg.norm, shared["ln2"], h, cfg.rms_norm_eps)
     z = h2 @ shared["mlp"]["wi"] + (h2 @ loras["a_mlp"][gi]) @ loras["b_mlp"][gi]
     if cfg.mlp == "gated_silu":
         gate, up = jnp.split(z, 2, axis=-1)
@@ -143,11 +143,11 @@ def _mamba_group_scan(cfg, gparams, x, states=None):
     def body(h, inp):
         if states is None:
             lp = inp
-            hn = apply_norm(cfg.norm, lp["ln1"], h)
+            hn = apply_norm(cfg.norm, lp["ln1"], h, cfg.rms_norm_eps)
             out, _ = mamba2_apply(lp["mixer"], hn, cfg)
             return h + out, None
         lp, st = inp
-        hn = apply_norm(cfg.norm, lp["ln1"], h)
+        hn = apply_norm(cfg.norm, lp["ln1"], h, cfg.rms_norm_eps)
         out, new_st = mamba2_apply(lp["mixer"], hn, cfg, state=st)
         return h + out, new_st
 
@@ -170,7 +170,7 @@ def forward(cfg: ModelConfig, params: dict, tokens: jax.Array):
         h = shared_fn(params["shared"], params["loras"], gi, x, x0)
         x = x + h
         x, _ = _mamba_group_scan(cfg, params["groups"][gi], x)
-    x = apply_norm(cfg.norm, params["final_norm"], x)
+    x = apply_norm(cfg.norm, params["final_norm"], x, cfg.rms_norm_eps)
     logits = jnp.einsum("bsd,dv->bsv", x, params["lm_head"],
                         preferred_element_type=jnp.float32)
     return logits, jnp.zeros((), jnp.float32)
@@ -206,7 +206,7 @@ def prefill(cfg: ModelConfig, params: dict, tokens: jax.Array, s_max: int):
         x, ssm = _mamba_group_scan(cfg, params["groups"][gi], x,
                                    states=caches[gi]["ssm"])
         new_caches.append({"ssm": ssm, "kv": kv})
-    x = apply_norm(cfg.norm, params["final_norm"], x[:, -1:])
+    x = apply_norm(cfg.norm, params["final_norm"], x[:, -1:], cfg.rms_norm_eps)
     logits = jnp.einsum("bsd,dv->bsv", x, params["lm_head"],
                         preferred_element_type=jnp.float32)
     return logits, new_caches
@@ -223,7 +223,7 @@ def decode_step(cfg: ModelConfig, params: dict, token: jax.Array, caches):
         x, ssm = _mamba_group_scan(cfg, params["groups"][gi], x,
                                    states=caches[gi]["ssm"])
         new_caches.append({"ssm": ssm, "kv": kv})
-    x = apply_norm(cfg.norm, params["final_norm"], x)
+    x = apply_norm(cfg.norm, params["final_norm"], x, cfg.rms_norm_eps)
     logits = jnp.einsum("bsd,dv->bsv", x, params["lm_head"],
                         preferred_element_type=jnp.float32)
     return logits, new_caches

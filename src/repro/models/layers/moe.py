@@ -150,3 +150,49 @@ def moe_apply(p: dict, x: jax.Array, *, top_k: int,
     p_e = probs.mean(0)
     aux = e * jnp.sum(f_e * p_e)
     return out.reshape(b, s, d), MoEMetrics(aux, dropped)
+
+
+# ---------------------------------------------------------------------------
+# Sigmoid-scored, bias-corrected routing (DeepSeek-V3 ``noaux_tc``, one
+# group) and a dropless expert FFN applied one expert at a time
+# ---------------------------------------------------------------------------
+
+def sigmoid_route(router: jax.Array, bias: jax.Array, h: jax.Array, *,
+                  top_k: int, scale: float) -> tuple[jax.Array, jax.Array]:
+    """h [..., D] -> (ids [..., K] int32, weights [..., K] f32).
+
+    Scores are ``sigmoid(h . router)`` in float32; the experts are the top
+    ``top_k`` by score + ``bias`` (the correction bias only selects); each
+    weight is its expert's unbiased score over the sum of the chosen
+    scores, times ``scale`` (``norm_topk_prob`` and
+    ``routed_scaling_factor``). With one group, group selection keeps
+    every expert. Every token gets its ``top_k`` experts: nothing is
+    dropped at a capacity."""
+    logits = jnp.einsum("...d,de->...e", h.astype(jnp.float32),
+                        router.astype(jnp.float32),
+                        precision=jax.lax.Precision.HIGHEST)
+    scores = jax.nn.sigmoid(logits)
+    _, ids = jax.lax.top_k(scores + bias.astype(jnp.float32), top_k)
+    w = jnp.take_along_axis(scores, ids, axis=-1)
+    w = w / (w.sum(-1, keepdims=True) + 1e-20)
+    return ids.astype(jnp.int32), w * scale
+
+
+def gated_silu(h: jax.Array, wi: jax.Array, wo: jax.Array) -> jax.Array:
+    """``wo(silu(gate) * up)`` with ``wi`` the gate's columns then the
+    up projection's; inputs in the weights' dtype, float32 out."""
+    g = jnp.einsum("...d,df->...f", h.astype(wi.dtype), wi,
+                   preferred_element_type=jnp.float32)
+    gate, up = jnp.split(g, 2, axis=-1)
+    a = (jax.nn.silu(gate) * up).astype(wo.dtype)
+    return jnp.einsum("...f,fd->...d", a, wo,
+                      preferred_element_type=jnp.float32)
+
+
+def expert_add(x: jax.Array, h: jax.Array, ids: jax.Array, w: jax.Array,
+               e: jax.Array, wi: jax.Array, wo: jax.Array) -> jax.Array:
+    """x + (each token's routing weight of expert ``e``, 0 where it was not
+    chosen) * expert ``e``'s FFN of h. Applying this for every expert any
+    token chose gives the dropless routed output."""
+    we = jnp.sum(jnp.where(ids == e, w, 0.0), axis=-1)
+    return x + we[..., None] * gated_silu(h, wi, wo)

@@ -29,7 +29,10 @@ def norm_params(kind: str, d: int):
     return {"scale": jnp.ones((d,), jnp.float32), "bias": jnp.zeros((d,), jnp.float32)}
 
 
-def apply_norm(kind: str, params, x: jax.Array) -> jax.Array:
+def apply_norm(kind: str, params, x: jax.Array,
+               eps: float = 1e-6) -> jax.Array:
+    """``eps`` is the RMS norm's (``ModelConfig.rms_norm_eps``); layer norm
+    keeps its own 1e-5."""
     if kind == "rms":
-        return rms_norm(x, params["scale"])
+        return rms_norm(x, params["scale"], eps)
     return layer_norm(x, params["scale"], params["bias"])

@@ -100,18 +100,18 @@ def block_apply(cfg: ModelConfig, p: dict, x: jax.Array, *,
     """Returns (x, new_cache, aux_loss)."""
     aux = jnp.zeros((), jnp.float32)
     if cfg.family == "ssm":
-        h, new_state = mamba2_apply(p["mixer"], apply_norm(cfg.norm, p["ln1"], x),
+        h, new_state = mamba2_apply(p["mixer"], apply_norm(cfg.norm, p["ln1"], x, cfg.rms_norm_eps),
                                     cfg, state=cache)
         return x + h, new_state, aux
     h, new_cache = attn_apply(
-        p["attn"], apply_norm(cfg.norm, p["ln1"], x),
+        p["attn"], apply_norm(cfg.norm, p["ln1"], x, cfg.rms_norm_eps),
         n_heads=cfg.n_heads, n_kv=cfg.n_kv_heads, head_dim=cfg.head_dim_,
         rope_theta=cfg.rope_theta, window=cfg.sliding_window,
         kv_chunk=cfg.attn_kv_chunk, blocks_threshold=cfg.attn_blocks_threshold,
         use_pallas=cfg.use_pallas_attention, pallas_interpret=cfg.pallas_interpret,
         cache=cache, positions=positions)
     x = x + h
-    h2 = apply_norm(cfg.norm, p["ln2"], x)
+    h2 = apply_norm(cfg.norm, p["ln2"], x, cfg.rms_norm_eps)
     if cfg.family == "moe":
         h2, metrics = moe_apply(p["moe"], h2, top_k=cfg.top_k,
                                 capacity_factor=cfg.capacity_factor,
@@ -158,7 +158,7 @@ def embed_tokens(cfg: ModelConfig, params: dict, tokens: jax.Array,
 
 
 def logits_from_hidden(cfg: ModelConfig, params: dict, x: jax.Array) -> jax.Array:
-    x = apply_norm(cfg.norm, params["final_norm"], x)
+    x = apply_norm(cfg.norm, params["final_norm"], x, cfg.rms_norm_eps)
     head = params["embed"].T if cfg.tie_embeddings else params["lm_head"]
     return jnp.einsum("bsd,dv->bsv", x, head, preferred_element_type=jnp.float32)
 

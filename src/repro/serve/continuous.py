@@ -14,9 +14,17 @@ measured RX (issued ``rx_async`` under INTERRUPT so the device->host copy
 overlaps the host-side slot bookkeeping) — the paper's balanced TX/RX goal
 applied to serving, with per-transfer stats in ``engine.stats``.
 
-Supports the KV-cache families (dense / moe / vlm); the cache carries
-per-slot lengths [L, B] so heterogeneous requests decode correctly in one
-batch (the attention layer handles vector cache lengths).
+Supports the KV-cache families (dense / moe / vlm) and the latent-cache
+family (mla_moe, whose model drives its own programs from the host,
+``Model.host_loop``: its prefill and decode are called as they are, not
+jitted). The cache carries per-slot lengths [L, B] so heterogeneous
+requests decode correctly in one batch (the attention layer handles vector
+cache lengths); every cache leaf with a slot axis is spliced, whatever the
+cache's structure.
+
+Span: ``repro.serve.step`` around :meth:`ContinuousBatchingEngine.step`,
+which starts a frame (:mod:`repro.utils.spans`) that every span inside the
+step shares.
 """
 
 from __future__ import annotations
@@ -44,6 +52,7 @@ from repro.core.transfer import (
     reassemble_chunks,
 )
 from repro.models.api import Model
+from repro.utils.spans import span
 
 
 @dataclass
@@ -143,8 +152,13 @@ class ContinuousBatchingEngine:
         # place (rx_async out=), so steady-state decode does zero per-step
         # host allocation on the detokenize path.
         self._tok_host = np.empty(n_slots, np.int32)
-        self._decode = jax.jit(model.decode)
-        self._prefill1 = jax.jit(lambda p, b: model.prefill(p, b, max_seq))
+        if model.host_loop:
+            self._decode = model.decode
+            self._prefill1 = lambda p, b: model.prefill(p, b, max_seq)
+        else:
+            self._decode = jax.jit(model.decode)
+            self._prefill1 = jax.jit(
+                lambda p, b: model.prefill(p, b, max_seq))
         self.steps = 0
         self.completed: list[Request] = []
 
@@ -228,6 +242,10 @@ class ContinuousBatchingEngine:
     def step(self) -> int:
         """Admit, decode one token for every active slot, retire. Returns
         the number of active slots served."""
+        with span("repro.serve.step", new_frame=True):
+            return self._step()
+
+    def _step(self) -> int:
         self._admit()
         active = [s for s, r in enumerate(self.slots) if r is not None]
         if not active:

@@ -17,6 +17,10 @@ Work handed to another thread carries its submitter's :func:`current`
 along, and the worker files what it timed with :func:`record`, so the
 worker's record names the span that caused it and shares its frame.
 
+Counters: :func:`count` files one reading of a named counter (a number
+of things, not an interval) under the frame open on the calling thread;
+:func:`counts` returns them, kept in a ring of their own.
+
 Every record is on the ``perf_counter_ns`` clock, and every name starts
 with ``repro.``. :func:`set_enabled` (``False``) stops all recording; a
 disabled span still stamps its own start and end, which callers read."""
@@ -61,6 +65,13 @@ class Record(NamedTuple):
     nbytes: int        # bytes the interval moved or packed (0 if none)
 
 
+class Count(NamedTuple):
+    name: str
+    value: int
+    t: int             # perf_counter_ns when it was counted
+    frame: int | None  # seq of the frame open on the counting thread
+
+
 # A kept record is packed into bytes, which the garbage collector does not
 # track: kept as tuples, every record would count towards a collection
 # until the ring fills, and the extra collections (full ones among them)
@@ -103,6 +114,7 @@ class _Local(threading.local):
 
 
 _records: collections.deque[bytes] = collections.deque(maxlen=CAPACITY)
+_counts: collections.deque[tuple] = collections.deque(maxlen=CAPACITY)
 _seq = itertools.count(1)
 _local = _Local()
 _enabled = True
@@ -173,6 +185,21 @@ def record(name: str, t0_ns: int, t1_ns: int, *, parent: int | None,
                               frame or 0, nbytes, _id(name), _local.thread))
 
 
+def count(name: str, value: int) -> None:
+    """File one reading of counter ``name`` under the calling thread's
+    open frame."""
+    if _enabled:
+        stack = _local.stack
+        frame = stack[-1][1] if stack else None
+        _counts.append((_id(name), int(value), perf_counter_ns(), frame))
+
+
+def counts() -> list[Count]:
+    """The counter readings held, oldest first."""
+    names = _names
+    return [Count(names[n], v, t, f) for n, v, t, f in list(_counts)]
+
+
 def snapshot() -> list[Record]:
     """The records held, oldest first."""
     names, threads = _names, dict(_threads)
@@ -184,6 +211,7 @@ def snapshot() -> list[Record]:
 
 def clear() -> None:
     _records.clear()
+    _counts.clear()
 
 
 def set_enabled(on: bool) -> None:

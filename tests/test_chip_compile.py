@@ -1,6 +1,6 @@
 """Compile the main path for a described TPU v5e, at real widths, without a
-chip: the RoShamBo layers, the four Pallas kernels and the staged-weight
-unpack. What the chip's compiler refuses (tiling, VMEM, HBM) fails here.
+chip: the RoShamBo layers, the four Pallas kernels, the staged-weight
+unpack and Moonlight's decode programs. What the chip's compiler refuses (tiling, VMEM, HBM) fails here.
 
 The topology is described inside a fixture, never at import: only one
 process at a time may load the TPU library, and every test worker imports
@@ -145,6 +145,48 @@ def test_qwen_serve_step_fits_one_chip(one_chip, step):
     used = (m.argument_size_in_bytes + m.output_size_in_bytes
             + m.temp_size_in_bytes)
     assert used < 16e9
+
+
+@pytest.mark.parametrize("program", ["route", "expert", "head"])
+def test_moonlight_decode_programs_compile(one_chip, program):
+    """A decode step's programs at Moonlight-16B-A3B's published widths (4
+    rows over a 4096-position latent cache): a MoE layer's attention and
+    router, one routed expert, the head."""
+    from repro.configs.moonlight_16b_a3b import config as moonlight_config
+    from repro.models import moonlight as ml
+    cfg = moonlight_config()
+    b, s_max, d, bf = 4, 4096, cfg.d_model, jnp.bfloat16
+    x = _sds(one_chip, (b, 1, d))
+    if program == "route":
+        from repro.models.layers.mla import mla_params
+        fs, e = cfg.n_shared_experts * cfg.d_expert, cfg.n_experts
+        attn = jax.eval_shape(
+            lambda: mla_params(jax.random.PRNGKey(0), cfg, bf))
+        lp = {"ln1": {"scale": _sds(one_chip, (d,))},
+              "ln2": {"scale": _sds(one_chip, (d,))},
+              "attn": jax.tree.map(
+                  lambda a: _sds(one_chip, a.shape, a.dtype), attn),
+              "moe": {"router": _sds(one_chip, (d, e), bf),
+                      "bias": _sds(one_chip, (e,)),
+                      "shared": {"wi": _sds(one_chip, (d, 2 * fs), bf),
+                                 "wo": _sds(one_chip, (fs, d), bf)}}}
+        c = ml.moonlight_route.lower(
+            cfg, 0, lp, x, _sds(one_chip, (b, s_max, cfg.kv_lora_rank), bf),
+            _sds(one_chip, (b, s_max, cfg.qk_rope_head_dim), bf),
+            _sds(one_chip, (b,), jnp.int32))
+    elif program == "expert":
+        k, fe = cfg.top_k, cfg.d_expert
+        c = ml.moonlight_expert.lower(
+            x, _sds(one_chip, (b, 1, d), bf),
+            _sds(one_chip, (b, 1, k), jnp.int32), _sds(one_chip, (b, 1, k)),
+            _sds(one_chip, (), jnp.int32), _sds(one_chip, (d, 2 * fe), bf),
+            _sds(one_chip, (fe, d), bf))
+    else:
+        c = ml.moonlight_head.lower(
+            cfg, _sds(one_chip, (d,)), _sds(one_chip, (d, cfg.vocab), bf), x,
+            _sds(one_chip, (), jnp.int32))
+    m = c.compile().memory_analysis()
+    assert m.temp_size_in_bytes < 64e6
 
 
 @pytest.mark.parametrize("block_bytes", [0, 1 << 20], ids=["unique", "blocks"])

@@ -146,6 +146,25 @@ def test_recorder_imports_no_jax():
     assert out.returncode == 0, out.stderr
 
 
+def test_counters_file_under_the_open_frame_and_clear():
+    with spans.span("repro.t.root", new_frame=True) as root:
+        spans.count("repro.t.moved", 21)
+        with spans.span("repro.t.child"):
+            spans.count("repro.t.moved", 3)
+    spans.count("repro.t.moved", 5)
+    got = [(c.name, c.value, c.frame) for c in spans.counts()]
+    assert got == [("repro.t.moved", 21, root._seq),
+                   ("repro.t.moved", 3, root._seq),
+                   ("repro.t.moved", 5, None)]
+    ts = [c.t for c in spans.counts()]
+    assert ts == sorted(ts)
+    spans.set_enabled(False)
+    spans.count("repro.t.moved", 7)
+    assert len(spans.counts()) == 3
+    spans.clear()
+    assert spans.counts() == []
+
+
 def test_disabled_recorder_records_nothing_but_still_times():
     spans.set_enabled(False)
     with spans.span("repro.t.off", new_frame=True) as s:
