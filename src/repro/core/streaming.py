@@ -167,6 +167,12 @@ class HostStreamingExecutor:
         ``run()`` returned."""
         return tuple(self._outputs)
 
+    @property
+    def window(self) -> int:
+        """Groups :meth:`stream_routed` keeps in flight: ``depth - 1`` of
+        the engine's policy, at least one."""
+        return max(1, self.engine.policy.depth - 1)
+
     def _rx_out(self, key: Any, y: jax.Array, *,
                 last: bool) -> list[np.ndarray] | None:
         if not self.zero_copy_rx or last:
@@ -247,7 +253,7 @@ class HostStreamingExecutor:
 
         A group goes as one ``tx_sg`` transaction, one segment per array,
         straight from the host arrays (no staging copy). Up to
-        ``depth - 1`` groups are in flight while the host hands landed ones
+        :attr:`window` groups are in flight while the host hands landed ones
         to ``apply_fn``, which dispatches device work without waiting for
         it: group j+1's TX overlaps group j's compute. INTERRUPT management
         only.
@@ -260,7 +266,7 @@ class HostStreamingExecutor:
         if engine.policy.management is not Management.INTERRUPT:
             raise ValueError("stream_routed needs INTERRUPT management")
         sizes = [sum(int(a.nbytes) for a in g) for g in groups]
-        window = max(1, engine.policy.depth - 1)
+        window = self.window
         pending: collections.deque = collections.deque()
         issued = 0
 

@@ -15,12 +15,16 @@ The routed experts do not fit in the device's memory, so they live as
 host arrays and :class:`ExpertOffload` runs a step layer by layer from the
 host. Per MoE layer: one program runs attention and the router; the host
 receives the routed expert ids (span ``repro.moe.route``); the shared MLP
-is dispatched; the distinct routed experts stream to the device
-(:meth:`repro.core.streaming.HostStreamingExecutor.stream_routed`), each
-applied as it lands; the layer ends when its output is complete (span
-``repro.moe.experts``; counter ``repro.moe.experts_moved``, the distinct
-experts moved). Experts given as device arrays are used in place: the
-resident path, for tests at small sizes.
+is dispatched; the distinct routed experts, in ascending order, are cut
+into a few groups (:func:`expert_groups`), each group streams to the
+device as one transaction
+(:meth:`repro.core.streaming.HostStreamingExecutor.stream_routed`) and is
+applied as it lands by one program, :func:`moonlight_expert`; the layer
+ends when its output is complete (span ``repro.moe.experts``; counters
+``repro.moe.experts_moved``, the distinct experts moved, and
+``repro.moe.expert_groups``, the groups they moved in). Experts given as
+device arrays are used in place, in the same groups: the resident path,
+for tests at small sizes.
 
 Weights (``params``, on the device): ``embed`` [V, D], ``lm_head`` [D, V],
 ``final_norm``, and ``layers``, one dict per layer: ``ln1``, ``attn``,
@@ -48,6 +52,8 @@ from repro.utils import spans
 from repro.utils.spans import span
 
 EXPERTS_MOVED = "repro.moe.experts_moved"
+EXPERT_GROUPS = "repro.moe.expert_groups"
+GROUPS_PER_SLOT = 2  # expert groups a layer is cut into per TX window slot
 F32 = jnp.float32
 
 
@@ -180,8 +186,14 @@ def moonlight_shared(x, h, wi, wo):
 
 
 @jax.jit
-def moonlight_expert(x, h, ids, w, e, wi, wo):
-    return expert_add(x, h, ids, w, e, wi, wo)
+def moonlight_expert(x, h, ids, w, es: tuple, ws: tuple):
+    """x plus the routed output of a group of experts: ``es`` their ids
+    (device scalars, ascending), ``ws`` their ``wi``, ``wo`` in the same
+    order. Added one expert at a time, in that order; one compiled program
+    per group size."""
+    for e, wi, wo in zip(es, ws[0::2], ws[1::2]):
+        x = expert_add(x, h, ids, w, e, wi, wo)
+    return x
 
 
 @functools.partial(jax.jit, static_argnums=(0,))
@@ -191,6 +203,35 @@ def moonlight_head(cfg, scale, head, x, last):
     h = rms_norm(x, scale, cfg.rms_norm_eps)
     return jnp.einsum("bsd,dv->bsv", h.astype(head.dtype), head,
                       preferred_element_type=F32)
+
+
+# ---------------------------------------------------------------------------
+# expert groups
+# ---------------------------------------------------------------------------
+
+def expert_groups(n: int, window: int) -> list[slice]:
+    """Cut a layer's ``n`` distinct routed experts (ascending) into
+    ``min(n, GROUPS_PER_SLOT * window)`` consecutive groups whose sizes
+    differ by at most one, the smaller first so that the group the layer
+    waits for before any overlap is the shortest. ``window``: the groups
+    the streaming executor keeps in flight."""
+    g = min(n, GROUPS_PER_SLOT * max(1, window))
+    if g <= 0:
+        return []
+    q, r = divmod(n, g)
+    out, i = [], 0
+    for j in range(g):
+        k = q + (j >= g - r)
+        out.append(slice(i, i + k))
+        i += k
+    return out
+
+
+def group_sizes(n_max: int, window: int) -> set[int]:
+    """Every group size :func:`expert_groups` gives for 1 to ``n_max``
+    experts."""
+    return {s.stop - s.start for n in range(1, n_max + 1)
+            for s in expert_groups(n, window)}
 
 
 # ---------------------------------------------------------------------------
@@ -208,7 +249,14 @@ class ExpertOffload:
     padding is never attended to, routed from or cached as valid.
 
     ``calls``: set to a list to record every prefill and decode
-    (:class:`Call`)."""
+    (:class:`Call`).
+
+    The experts of a MoE layer move and apply in :func:`expert_groups`
+    over the executor's TX window (``HostStreamingExecutor.window``; the
+    resident path groups as a ring of depth 4 would). The first time a
+    shape of step is seen, the expert program is compiled for every group
+    size the rule can give there, so that a later step compiles
+    nothing."""
 
     def __init__(self, cfg: ModelConfig, experts: list[dict],
                  executor: Any = None, *, prefill_pad: int = 0):
@@ -221,7 +269,10 @@ class ExpertOffload:
         self.executor = executor
         self.prefill_pad = prefill_pad
         self.calls: list[Call] | None = None
+        # the resident path groups as a ring of depth 4 would
+        self.window = executor.window if executor is not None else 3
         self._eids: list[jax.Array] = []
+        self._compiled: dict[tuple, dict] = {}  # by step shapes
 
     def model(self) -> Model:
         """The serving engine's view: prefill and decode drive their own
@@ -244,25 +295,49 @@ class ExpertOffload:
             self._eids.append(jnp.asarray(len(self._eids), jnp.int32))
         return self._eids[e]
 
+    def _programs(self, ex: dict, x, h, ids, w) -> dict:
+        """The expert program, compiled ahead of time, by group size, for
+        this step's shapes: the first time a shape of step is seen, for
+        every group size the rule can give there."""
+        key = (x.shape, ids.shape)
+        if key not in self._compiled:
+            def sds(a):
+                return jax.ShapeDtypeStruct(a.shape, a.dtype)
+
+            eid = jax.ShapeDtypeStruct((), jnp.int32)
+            wts = (sds(ex["wi"][0]), sds(ex["wo"][0]))
+            n_max = min(self.cfg.n_experts, ids.size)
+            self._compiled[key] = {
+                k: moonlight_expert.lower(sds(x), sds(h), sds(ids), sds(w),
+                                          (eid,) * k, wts * k).compile()
+                for k in sorted(group_sizes(n_max, self.window))}
+        return self._compiled[key]
+
     def _experts(self, m: int, lp: dict, x, h, ids, w,
                  chosen: np.ndarray) -> jax.Array:
         sel = [int(e) for e in np.unique(chosen)]
+        groups = [sel[g] for g in expert_groups(len(sel), self.window)]
         spans.count(EXPERTS_MOVED, len(sel))
+        spans.count(EXPERT_GROUPS, len(groups))
         ex = self.experts[m]
+        programs = self._programs(ex, x, h, ids, w)
         with span("repro.moe.experts"):
+            arrays = [[a for e in g for a in (ex["wi"][e], ex["wo"][e])]
+                      for g in groups]
             sh = lp["moe"]["shared"]
             x = moonlight_shared(x, h, sh["wi"], sh["wo"])
 
             def apply(j: int, dev: list) -> None:
                 nonlocal x
-                x = moonlight_expert(x, h, ids, w, self._eid(sel[j]), *dev)
+                x = programs[len(groups[j])](
+                    x, h, ids, w, tuple(self._eid(e) for e in groups[j]),
+                    tuple(dev))
 
             if self.executor is None:
-                for j, e in enumerate(sel):
-                    apply(j, [ex["wi"][e], ex["wo"][e]])
+                for j, g in enumerate(arrays):
+                    apply(j, g)
             else:
-                self.executor.stream_routed(
-                    [(ex["wi"][e], ex["wo"][e]) for e in sel], apply)
+                self.executor.stream_routed(arrays, apply)
             x.block_until_ready()
         return x
 

@@ -151,7 +151,8 @@ def test_qwen_serve_step_fits_one_chip(one_chip, step):
 def test_moonlight_decode_programs_compile(one_chip, program):
     """A decode step's programs at Moonlight-16B-A3B's published widths (4
     rows over a 4096-position latent cache): a MoE layer's attention and
-    router, one routed expert, the head."""
+    router, the largest group of routed experts a decode step applies in
+    one program, the head."""
     from repro.configs.moonlight_16b_a3b import config as moonlight_config
     from repro.models import moonlight as ml
     cfg = moonlight_config()
@@ -176,11 +177,13 @@ def test_moonlight_decode_programs_compile(one_chip, program):
             _sds(one_chip, (b,), jnp.int32))
     elif program == "expert":
         k, fe = cfg.top_k, cfg.d_expert
+        group = max(ml.group_sizes(b * k, 3))
         c = ml.moonlight_expert.lower(
             x, _sds(one_chip, (b, 1, d), bf),
             _sds(one_chip, (b, 1, k), jnp.int32), _sds(one_chip, (b, 1, k)),
-            _sds(one_chip, (), jnp.int32), _sds(one_chip, (d, 2 * fe), bf),
-            _sds(one_chip, (fe, d), bf))
+            (_sds(one_chip, (), jnp.int32),) * group,
+            (_sds(one_chip, (d, 2 * fe), bf), _sds(one_chip, (fe, d), bf))
+            * group)
     else:
         c = ml.moonlight_head.lower(
             cfg, _sds(one_chip, (d,)), _sds(one_chip, (d, cfg.vocab), bf), x,

@@ -164,7 +164,32 @@ def test_router_is_the_published_one_and_drops_nothing(model, t):
         rtol=1e-4, atol=1e-5)
 
 
-@pytest.mark.parametrize("depth", [2, 4])
+@pytest.mark.parametrize("window", [1, 2, 3])
+def test_expert_groups_cover_each_expert_once_in_near_equal_groups(window):
+    rng = np.random.default_rng(window)
+    for n in range(1, 65):
+        sel = np.sort(rng.choice(64, n, replace=False))
+        groups = ml.expert_groups(n, window)
+        assert len(groups) == min(n, ml.GROUPS_PER_SLOT * window)
+        # every expert exactly once, in ascending order
+        assert np.array_equal(np.concatenate([sel[g] for g in groups]), sel)
+        sizes = [g.stop - g.start for g in groups]
+        assert min(sizes) >= 1 and max(sizes) - min(sizes) <= 1
+        assert sizes == sorted(sizes)
+    assert max(ml.group_sizes(64, window)) == -(-64 // (ml.GROUPS_PER_SLOT
+                                                        * window))
+
+
+@pytest.mark.parametrize("depth", [2, 3, 4])
+def test_runner_groups_over_the_executors_window(model, depth):
+    cfg, _params, experts, on_device = model
+    with TransferEngine(TransferPolicy.kernel_level_ring(depth)) as te:
+        runner = ml.ExpertOffload(cfg, experts, HostStreamingExecutor(te))
+    assert runner.window == depth - 1
+    assert ml.ExpertOffload(cfg, on_device).window == 3  # as at depth 4
+
+
+@pytest.mark.parametrize("depth", [2, 3, 4])
 def test_streamed_decode_equals_resident_and_reference(model, depth):
     cfg, params, experts, on_device = model
     toks = _tokens(16, seed=2)
@@ -179,32 +204,86 @@ def test_streamed_decode_equals_resident_and_reference(model, depth):
     np.testing.assert_allclose(streamed, want[8:], rtol=1e-4, atol=1e-4)
 
 
-def test_bytes_moved_per_layer_are_the_routed_experts(model):
+def _decode_rows(cfg, rows):
+    cache = ml.init_cache(cfg, rows, S_MAX)
+    cache = cache._replace(length=jnp.zeros((cfg.n_layers, rows), jnp.int32))
+    return jnp.arange(1, rows + 1)[:, None], cache
+
+
+@pytest.mark.parametrize("depth", [2, 3, 4])
+def test_bytes_moved_per_layer_are_the_routed_experts(model, depth):
     cfg, params, experts, _on_device = model
     per_expert = experts[0]["wi"][0].nbytes + experts[0]["wo"][0].nbytes
-    with TransferEngine(TransferPolicy.kernel_level_ring(4)) as te:
+    submits = []
+    with TransferEngine(TransferPolicy.kernel_level_ring(depth)) as te:
+        tx_sg = te.tx_sg
+
+        def counting(segments, *a, **kw):
+            submits.append(len(segments) // 2)
+            return tx_sg(segments, *a, **kw)
+
+        te.tx_sg = counting
         runner = ml.ExpertOffload(cfg, experts, HostStreamingExecutor(te),
                                   prefill_pad=16)
         runner.calls = []
-        cache = ml.init_cache(cfg, 3, S_MAX)
-        cache = cache._replace(length=jnp.zeros((cfg.n_layers, 3), jnp.int32))
+        token, cache = _decode_rows(cfg, 3)
         spans.clear()
         before = te.tx_bytes_total
         with spans.span("repro.test.step", new_frame=True) as root:
-            runner.decode(params, jnp.asarray([[1], [2], [3]]), cache)
+            runner.decode(params, token, cache)
         moved = te.tx_bytes_total - before
     routes = runner.calls[-1].routes
     distinct = [len(np.unique(r)) for r in routes]
+    sizes = [[g.stop - g.start for g in ml.expert_groups(n, depth - 1)]
+             for n in distinct]
     assert moved == sum(distinct) * per_expert
+    # one tx_sg per group, of the group's experts
+    assert submits == [k for layer in sizes for k in layer]
     recs = [r for r in spans.snapshot() if r.frame == root._seq]
     layers = [r for r in recs if r.name == "repro.moe.experts"]
     assert len(layers) == len(distinct)
-    for lay, n in zip(layers, distinct):
+    for lay, ks in zip(layers, sizes):
         tx = [r for r in recs if r.name == "repro.stream.expert_tx"
               and lay.t0 <= r.t0 and r.t1 <= lay.t1]
-        assert len(tx) == n and sum(r.nbytes for r in tx) == n * per_expert
-    assert [c.value for c in spans.counts()
-            if c.frame == root._seq] == distinct
+        assert [r.nbytes for r in tx] == [k * per_expert for k in ks]
+    counted = [c for c in spans.counts() if c.frame == root._seq]
+    assert [c.value for c in counted
+            if c.name == ml.EXPERTS_MOVED] == distinct
+    assert [c.value for c in counted
+            if c.name == ml.EXPERT_GROUPS] == [len(k) for k in sizes]
+
+
+@pytest.mark.parametrize("depth", [2, 3, 4])
+def test_no_expert_program_compiles_after_warm_up(model, depth):
+    """The first MoE layer of a shape compiles the expert program for every
+    group size the rule can give there, whatever that layer routed; no
+    later layer compiles it."""
+    cfg, params, experts, _on_device = model
+    rows = depth + 2  # a shape of its own per case
+    lp = params["layers"][cfg.first_dense_layers]
+    token, cache = _decode_rows(cfg, rows)
+    x = ml.moonlight_embed(params["embed"], token)
+    x, h, ids, w, _c, _kr = ml.moonlight_route(
+        cfg, 0, lp, x, cache.c[1], cache.kr[1], cache.length[1])
+    n_max = min(cfg.n_experts, rows * cfg.top_k)
+    assert len(ml.group_sizes(n_max, depth - 1)) > 1
+    compiles = []
+
+    def on_event(event, **_kw):
+        if event == "/jax/compilation_cache/compile_requests_use_cache":
+            compiles.append(event)
+
+    with TransferEngine(TransferPolicy.kernel_level_ring(depth)) as te:
+        runner = ml.ExpertOffload(cfg, experts, HostStreamingExecutor(te))
+        runner._experts(0, lp, x, h, ids, w, np.asarray([[0]]))  # one expert
+        jax.monitoring.register_event_listener(on_event)
+        try:
+            for n in range(1, n_max + 1):
+                chosen = np.arange(cfg.n_experts - n, cfg.n_experts)[None]
+                runner._experts(0, lp, x, h, ids, w, chosen)
+        finally:
+            jax.monitoring.unregister_event_listener(on_event)
+    assert compiles == []
 
 
 def test_served_through_the_engine_matches_reference(model):
